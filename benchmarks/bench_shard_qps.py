@@ -164,10 +164,10 @@ def _stage_breakdown(mesh, b: int, k: int, shard_flat: int, bud: int,
         (g,) = dist.gather_survivors("model", s[0])
         return gh, g
 
-    coll = jax.jit(dist.shard_map(
-        _coll_body, mesh,
+    coll = jax.jit(jax.shard_map(
+        _coll_body, mesh=mesh,
         in_specs=(P("model", None, None), P("model", None, None)),
-        out_specs=(P(), P())))
+        out_specs=(P(), P()), check_vma=False))
     h_sh = jnp.broadcast_to(hist, (N_SHARDS, b, m + 1))
     s_sh = jnp.broadcast_to(surv, (N_SHARDS, b, bud))
 

@@ -30,19 +30,6 @@ from repro.core import buffer as rb
 INF = jnp.inf
 
 
-def shard_map(f, mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` (jax >= 0.6 exposes it at top level;
-    0.4.x under ``jax.experimental``).  Replication checking is disabled:
-    the search bodies end in ``psum``/``all_gather`` + replicated math, which
-    the checker cannot always prove."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def _axes_tuple(axis_name) -> tuple[str, ...]:
     return (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
 

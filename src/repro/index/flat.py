@@ -10,7 +10,8 @@ import jax.numpy as jnp
 @functools.partial(jax.jit, static_argnames=("k",))
 def search(x: jax.Array, q: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
     """Exact top-k by Euclidean distance for one query."""
-    d2 = jnp.sum(x * x, axis=1) - 2.0 * (x @ q) + jnp.sum(q * q)
+    xq = jnp.matmul(x, q, precision="highest")
+    d2 = jnp.sum(x * x, axis=1) - 2.0 * xq + jnp.sum(q * q)
     neg, idx = jax.lax.top_k(-d2, k)
     return jnp.sqrt(jnp.maximum(-neg, 0.0)), idx.astype(jnp.int32)
 

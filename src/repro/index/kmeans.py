@@ -15,7 +15,7 @@ def _pairwise_sq(x: jax.Array, c: jax.Array) -> jax.Array:
     """||x - c||^2 via the matmul identity (MXU-friendly)."""
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)
     c2 = jnp.sum(c * c, axis=-1)
-    return x2 + c2 - 2.0 * (x @ c.T)
+    return x2 + c2 - 2.0 * jnp.matmul(x, c.T, precision="highest")
 
 
 def assign(x: jax.Array, centroids: jax.Array) -> jax.Array:
@@ -35,7 +35,7 @@ def kmeans(
         a = assign(x, cent)
         one = jax.nn.one_hot(a, n_clusters, dtype=x.dtype)      # (n, K)
         counts = jnp.sum(one, axis=0)                            # (K,)
-        sums = one.T @ x                                         # (K, d)
+        sums = jnp.matmul(one.T, x, precision="highest")         # (K, d)
         newc = sums / jnp.maximum(counts, 1.0)[:, None]
         # keep empty clusters where they were
         newc = jnp.where(counts[:, None] > 0, newc, cent)

@@ -57,7 +57,7 @@ def encode(cb: PQCodebook, x: jax.Array) -> jax.Array:
         d2 = (
             jnp.sum(xm * xm, -1, keepdims=True)
             + jnp.sum(cm * cm, -1)
-            - 2.0 * xm @ cm.T
+            - 2.0 * jnp.matmul(xm, cm.T, precision="highest")
         )
         return jnp.argmin(d2, -1)
 

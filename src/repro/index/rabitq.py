@@ -52,7 +52,7 @@ def encode(key: jax.Array, x: jax.Array, centroids: jax.Array,
     r = x - centroids[assignment]
     norm_o = jnp.linalg.norm(r, axis=1)
     unit = r / jnp.maximum(norm_o, 1e-12)[:, None]
-    u = unit @ rot.T                      # P ō
+    u = jnp.matmul(unit, rot.T, precision="highest")     # P ō
     codes = jnp.where(u >= 0, 1, -1).astype(jnp.int8)
     f_o = jnp.sum(jnp.abs(u), axis=1) / jnp.sqrt(jnp.float32(d))
     return RabitqCodes(rot=rot, codes=codes, norm_o=norm_o,
@@ -68,7 +68,8 @@ class QueryFactors(NamedTuple):
 def query_factors(rq: RabitqCodes, q: jax.Array, centroid: jax.Array) -> QueryFactors:
     qr = q - centroid
     norm_q = jnp.linalg.norm(qr)
-    v = (qr / jnp.maximum(norm_q, 1e-12)) @ rq.rot.T
+    v = jnp.matmul(qr / jnp.maximum(norm_q, 1e-12), rq.rot.T,
+                   precision="highest")
     return QueryFactors(v=v, norm_q=norm_q)
 
 
@@ -82,7 +83,8 @@ def estimate(
     """Returns (est_dist, lb, ub) — actual distances (sqrt of the squared
     form), lower bound clamped at 0."""
     d = codes.shape[1]
-    xv = (codes.astype(jnp.float32) @ qf.v) / jnp.sqrt(jnp.float32(d))  # <x̄,v>
+    xv = jnp.matmul(codes.astype(jnp.float32), qf.v,
+                    precision="highest") / jnp.sqrt(jnp.float32(d))  # <x̄,v>
     ip = xv / f_o
     err = eps0 * jnp.sqrt((1.0 - f_o ** 2) / (f_o ** 2 * (d - 1)))
     scale = 2.0 * qf.norm_q * norm_o

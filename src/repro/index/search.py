@@ -125,7 +125,7 @@ def build_rabitq_index(key, x, n_clusters: int, n_iter: int = 10) -> RabitqIndex
     index = ivf_mod.build(k1, x, n_clusters, n_iter)
     assignment = jnp.argmin(
         jnp.sum(x * x, 1, keepdims=True)
-        - 2 * x @ index.centroids.T
+        - 2 * jnp.matmul(x, index.centroids.T, precision="highest")
         + jnp.sum(index.centroids ** 2, 1),
         axis=1,
     )
@@ -141,8 +141,9 @@ def _exact_dists(vectors: jax.Array, ids: jax.Array, q: jax.Array) -> jax.Array:
     """Exact Euclidean distances for a gathered id set (ids may contain -1
     padding; callers mask)."""
     v = vectors[jnp.maximum(ids, 0)]
+    vq = jnp.matmul(v, q, precision="highest")
     return jnp.sqrt(jnp.maximum(
-        jnp.sum(v * v, -1) - 2.0 * (v @ q) + jnp.sum(q * q), 0.0))
+        jnp.sum(v * v, -1) - 2.0 * vq + jnp.sum(q * q), 0.0))
 
 
 def _stream_from(est, ids, valid) -> col.StreamInput:
@@ -895,15 +896,20 @@ def _rabitq_inline_rank(k: int, st: int, n_probe: int, k_cb: int) -> int:
 
 
 def _rabitq_sample_plan(sample_ub: jax.Array, k: int, count: int, st: int,
-                        n_probe: int, m: int):
+                        n_probe: int, m: int, scale_rank: bool = True):
     """Per-query codebook + static inline gate from the sample-prefix upper
     bounds.  One top-k serves both: the codebook quantiles (anchored at k,
     like the two-phase plan's ub top-k) and the rank-scaled ``count``-th-ub
-    seed whose bucket (+ margin) is the static ``tau_inline``."""
+    seed whose bucket (+ margin) is the static ``tau_inline``.
+
+    ``scale_rank=False`` takes the ``count``-th sample ub itself: the sample
+    is a subset of the probed lanes, so its ``count``-th smallest ub is at
+    or above the probed set's, and the gate covers the whole band."""
     k_cb = min(k, sample_ub.shape[1])
     topk_s = -jax.lax.top_k(-sample_ub, k_cb)[0]              # (B, k_cb) asc
     cbs = jax.vmap(lambda t: rb.build_codebook_from_topk(t, m=m))(topk_s)
-    rank = _rabitq_inline_rank(count, st, n_probe, k_cb)
+    rank = (_rabitq_inline_rank(count, st, n_probe, k_cb) if scale_rank
+            else min(count, k_cb))
     kth_s = topk_s[:, rank - 1]
     tau_static = jax.vmap(lambda c, v: rb.bucketize(c, v[None])[0])(cbs,
                                                                     kth_s)
@@ -1120,8 +1126,11 @@ def _ivf_rabitq_fused_batch(index, stream, qs, layout, probed, lane_valid,
         spos, sok = ivf_mod.tile_positions(layout, probed[:, :st], ivf.cap)
         sample_ub = jnp.where(sok, jnp.take_along_axis(ub, spos, axis=1),
                               INF)
+    # the kernel takes its gate before the scan and cannot refresh it from
+    # the scan's own histogram (the composed form below does), so its static
+    # gate is the unscaled sample order statistic, which covers the band
     cbs, tau_static = _rabitq_sample_plan(sample_ub, k, count, st, n_probe,
-                                          m)
+                                          m, scale_rank=not kernel)
     if pred_state is not None:
         # the EMA gate, exactly as it gates the PQ pool: -1 while cold
         # (nothing certified inline — the first batch behaves like the
@@ -1196,13 +1205,17 @@ def _ivf_rabitq_fused_batch(index, stream, qs, layout, probed, lane_valid,
             lambda f, p, v, o: f.at[jnp.where(o, p, n_flat)].set(v))(
                 filled, pos, sd, okp)[:, :n_flat]
         exact_band = jnp.where(certified, exact_c, filled)
+        # only the overflowing queries take the dense values: a query's
+        # result must not depend on which other queries share its batch
+        # (the dense and gathered exact legs round differently on TPU)
+        overflow = n_second > budget                          # (B,)
 
         def dense(_):
             allx = ops.l2_exact_batch(stream.vectors, qs, backend=backend)
-            return jnp.where(certified, exact_c, allx)
+            return jnp.where(overflow[:, None],
+                             jnp.where(certified, exact_c, allx), exact_band)
 
-        overflow = jnp.any(n_second > budget)
-        exact_band = jax.lax.cond(overflow, dense,
+        exact_band = jax.lax.cond(jnp.any(overflow), dense,
                                   lambda _: exact_band, None)
         exact_band = jnp.where(band, exact_band, INF)
     else:
@@ -1327,8 +1340,9 @@ def _exact_at_positions(svecs: jax.Array, qs: jax.Array, pos: jax.Array,
     def one(a):
         p, o, q = a
         v = svecs[jnp.where(o, p, 0)]
+        vq = jnp.matmul(v, q, precision="highest")
         d = jnp.sqrt(jnp.maximum(
-            jnp.sum(v * v, -1) - 2.0 * (v @ q) + jnp.sum(q * q), 0.0))
+            jnp.sum(v * v, -1) - 2.0 * vq + jnp.sum(q * q), 0.0))
         return jnp.where(o, d, INF)
 
     return jax.lax.map(one, (pos, ok, qs))
@@ -1614,13 +1628,13 @@ def ivf_search_sharded(
         count = max(pred_count, k) if pred_count is not None else k
         args.append(rerank.predict_tau(pred_state, count))
         in_specs.append(P())
-        fn = dist.shard_map(body, mesh, in_specs=tuple(in_specs),
-                            out_specs=out_specs + (P(),))
+        fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                           out_specs=out_specs + (P(),), check_vma=False)
         d, i, n, ghist = fn(*args)
         res = SearchResult(d, i, n, jnp.zeros_like(n))
         return res, rerank.predictor_update(pred_state, ghist)
-    fn = dist.shard_map(body, mesh, in_specs=tuple(in_specs),
-                        out_specs=out_specs)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                       out_specs=out_specs, check_vma=False)
     d, i, n = fn(*args)
     return SearchResult(d, i, n, jnp.zeros_like(n))
 
@@ -1773,13 +1787,13 @@ def ivf_pq_search_sharded(
     if predictive:
         args.append(rerank.predict_tau(pred_state, count))
         in_specs.append(P())
-        fn = dist.shard_map(body, mesh, in_specs=tuple(in_specs),
-                            out_specs=out_specs + (P(),))
+        fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                           out_specs=out_specs + (P(),), check_vma=False)
         d, i, n_rr, ghist = fn(*args)
         res = SearchResult(d, i, n_rr, jnp.zeros_like(n_rr))
         return res, rerank.predictor_update(pred_state, ghist)
-    fn = dist.shard_map(body, mesh, in_specs=tuple(in_specs),
-                        out_specs=out_specs)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                       out_specs=out_specs, check_vma=False)
     d, i, n_rr = fn(*args)
     return SearchResult(d, i, n_rr, jnp.zeros_like(n_rr))
 
@@ -1970,12 +1984,12 @@ def ivf_rabitq_search_sharded(
         in_specs.append(P())
     out_specs = (P(), P(), P(), P())
     if predictive:
-        fn = dist.shard_map(body, mesh, in_specs=tuple(in_specs),
-                            out_specs=out_specs + (P(),))
+        fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                           out_specs=out_specs + (P(),), check_vma=False)
         d, i, n_rr, n_second, ghist = fn(*args)
         res = SearchResult(d, i, n_rr, n_second)
         return res, rerank.predictor_update(pred_state, ghist)
-    fn = dist.shard_map(body, mesh, in_specs=tuple(in_specs),
-                        out_specs=out_specs)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                       out_specs=out_specs, check_vma=False)
     d, i, n_rr, n_second = fn(*args)
     return SearchResult(d, i, n_rr, n_second)

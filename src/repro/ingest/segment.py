@@ -167,8 +167,8 @@ def delta_scan_sharded(mesh, qs: jax.Array, svecs: jax.Array,
         lids = jnp.where(jnp.isfinite(neg), ids[pos], -1)
         return dist.gather_survivors(axes, -neg, lids)
 
-    fn = dist.shard_map(
-        body, mesh,
+    fn = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(P(), P(axes, None, None), P(axes, None), P(axes, None)),
-        out_specs=(P(), P()))
+        out_specs=(P(), P()), check_vma=False)
     return fn(qs, svecs, sids, slive)

@@ -26,84 +26,6 @@ TILE = 512
 BQ = 8   # query-batch chunk width inside the batched kernel
 
 
-def _bucket_kernel(dists_ref, wmask_ref, ew_map_ref, scal_ref,
-                   bucket_ref, hist_ref, *, m: int, hist_pad: int):
-    d = dists_ref[...][0]                        # (TILE,)
-    w = wmask_ref[...][0]                        # (TILE,) int32
-    ew = ew_map_ref[...]                         # (1, n_ew) int32
-    s = scal_ref[...]
-    d_min, delta = s[0, 0], s[0, 1]
-    n_ew = ew.shape[1]
-    tile = d.shape[0]
-
-    bin_f = jnp.floor((d - d_min) / delta)
-    overflow = bin_f >= n_ew
-    bin_id = jnp.clip(bin_f, 0, n_ew - 1).astype(jnp.int32)
-    # LUT via one-hot matmul (256-wide).
-    iota = jax.lax.broadcasted_iota(jnp.int32, (tile, n_ew), 1)
-    onehot = (iota == bin_id[:, None]).astype(jnp.float32)
-    bucket = jax.lax.dot_general(
-        onehot, ew.reshape(n_ew, 1).astype(jnp.float32),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )[:, 0].astype(jnp.int32)
-    bucket = jnp.where(overflow, m, bucket)
-    bucket_ref[...] = bucket[None, :]
-
-    # Histogram of this tile (weighted by validity), accumulated across grid.
-    hiota = jax.lax.broadcasted_iota(jnp.int32, (tile, hist_pad), 1)
-    hoh = jnp.where(hiota == bucket[:, None], w[:, None], 0)
-    tile_hist = jnp.sum(hoh, axis=0, dtype=jnp.int32)
-
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        hist_ref[...] = jnp.zeros_like(hist_ref)
-
-    hist_ref[...] += tile_hist[None, :]
-
-
-def bucket_hist_pallas(
-    dists: jax.Array,    # (n,) fp32, n % tile == 0 (invalid lanes = +inf)
-    valid: jax.Array,    # (n,) bool
-    d_min: jax.Array,
-    delta: jax.Array,
-    ew_map: jax.Array,   # (n_ew,) int32
-    m: int,
-    tile: int = TILE,
-    interpret: bool | None = None,
-) -> tuple[jax.Array, jax.Array]:
-    """Returns (bucket_ids (n,), hist (m+1,))."""
-    interpret = resolve_interpret(interpret)
-    n = dists.shape[0]
-    g = n // tile
-    n_ew = ew_map.shape[0]
-    hist_pad = ((m + 1 + 127) // 128) * 128
-    scal = jnp.zeros((1, 128), jnp.float32)
-    scal = scal.at[0, 0].set(d_min.astype(jnp.float32))
-    scal = scal.at[0, 1].set(delta.astype(jnp.float32))
-    w = valid.astype(jnp.int32)
-    bucket, hist = pl.pallas_call(
-        functools.partial(_bucket_kernel, m=m, hist_pad=hist_pad),
-        grid=(g,),
-        in_specs=[
-            pl.BlockSpec((1, tile), lambda i: (0, i)),
-            pl.BlockSpec((1, tile), lambda i: (0, i)),
-            pl.BlockSpec((1, n_ew), lambda i: (0, 0)),
-            pl.BlockSpec((1, 128), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, tile), lambda i: (i, 0)),
-            pl.BlockSpec((1, hist_pad), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((g, tile), jnp.int32),
-            jax.ShapeDtypeStruct((1, hist_pad), jnp.int32),
-        ],
-        interpret=interpret,
-    )(dists.reshape(1, n), w.reshape(1, n), ew_map.reshape(1, n_ew), scal)
-    return bucket.reshape(n), hist[0, : m + 1]
-
-
 # --------------------------------------------------------------------------
 # Batched (multi-query) bucketize + histogram
 # --------------------------------------------------------------------------

@@ -29,147 +29,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.l2_rerank import BQ, exact_tile
 from repro.kernels.platform import resolve_interpret
+from repro.kernels.pq_adc import adc_tile
 
 TILE = 256
 MC = 32
-BQ = 8   # query-batch chunk width inside the batched kernels
-
-
-def _fused_kernel(codes_ref, vecs_ref, wmask_ref, lut_ref, qv_ref, ew_map_ref,
-                  scal_ref, est_ref, bucket_ref, early_ref, hist_ref,
-                  nmiss_ref, *, m: int, hist_pad: int, mc: int):
-    codes = codes_ref[...].astype(jnp.int32)      # (TILE, M)
-    vecs = vecs_ref[...]                          # (TILE, d)
-    w = wmask_ref[...][0]                         # (TILE,)
-    lut = lut_ref[...]                            # (M, K)
-    qv = qv_ref[...]                              # (1, d)
-    ew = ew_map_ref[...]                          # (1, n_ew)
-    s = scal_ref[...]
-    d_min, delta, q_sq = s[0, 0], s[0, 1], s[0, 3]
-    tau_pred = s[0, 2].astype(jnp.int32)
-    tile, m_sub = codes.shape
-    k_codes = lut.shape[1]
-    n_ew = ew.shape[1]
-    inf = jnp.float32(jnp.inf)
-
-    # --- ADC estimate (chunked one-hot matmul) ---
-    def body(i, acc):
-        cs = jax.lax.dynamic_slice_in_dim(codes, i * mc, mc, axis=1)
-        ls = jax.lax.dynamic_slice_in_dim(lut, i * mc, mc, axis=0)
-        iota = jax.lax.broadcasted_iota(jnp.int32, (tile, mc, k_codes), 2)
-        onehot = (iota == cs[:, :, None]).astype(ls.dtype)
-        part = jax.lax.dot_general(
-            onehot.reshape(tile, mc * k_codes), ls.reshape(mc * k_codes, 1),
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        return acc + part[:, 0]
-
-    est2 = jax.lax.fori_loop(0, m_sub // mc, body,
-                             jnp.zeros((tile,), jnp.float32))
-    est = jnp.sqrt(jnp.maximum(est2, 0.0))
-    est = jnp.where(w > 0, est, inf)
-    est_ref[...] = est[None, :]
-
-    # --- bucketize (Eq. 6, one-hot LUT) ---
-    bin_f = jnp.floor((est - d_min) / delta)
-    overflow = bin_f >= n_ew
-    bin_id = jnp.clip(bin_f, 0, n_ew - 1).astype(jnp.int32)
-    iota2 = jax.lax.broadcasted_iota(jnp.int32, (tile, n_ew), 1)
-    onehot2 = (iota2 == bin_id[:, None]).astype(jnp.float32)
-    bucket = jax.lax.dot_general(
-        onehot2, ew.reshape(n_ew, 1).astype(jnp.float32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-    )[:, 0].astype(jnp.int32)
-    bucket = jnp.where(overflow, m, bucket)
-    bucket_ref[...] = bucket[None, :]
-
-    # --- histogram accumulation (the only cross-tile state) ---
-    hiota = jax.lax.broadcasted_iota(jnp.int32, (tile, hist_pad), 1)
-    tile_hist = jnp.sum(
-        jnp.where(hiota == bucket[:, None], w[:, None], 0), axis=0,
-        dtype=jnp.int32)
-
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        hist_ref[...] = jnp.zeros_like(hist_ref)
-        nmiss_ref[...] = jnp.zeros_like(nmiss_ref)
-
-    hist_ref[...] += tile_hist[None, :]
-
-    # --- early exact re-rank (Alg. 4): vectors are already in VMEM ---
-    xv = jax.lax.dot_general(
-        vecs, qv.reshape(-1, 1), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)[:, 0]
-    x_sq = jnp.sum(vecs * vecs, axis=1)
-    exact = jnp.sqrt(jnp.maximum(x_sq - 2.0 * xv + q_sq, 0.0))
-    pred = (w > 0) & (bucket <= tau_pred)
-    early_ref[...] = jnp.where(pred, exact, inf)[None, :]
-
-    # --- miss count: valid lanes the prediction left to the second pass ---
-    cnt = jnp.sum(((w > 0) & ~pred).astype(jnp.int32))
-    miota = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
-    nmiss_ref[...] += jnp.where(miota == 0, cnt, 0)
-
-
-def fused_scan_pallas(
-    codes: jax.Array,     # (n, M) int32/uint8, n % tile == 0, M % mc == 0
-    vectors: jax.Array,   # (n, d) fp32
-    valid: jax.Array,     # (n,) bool
-    lut: jax.Array,       # (M, K) fp32
-    q: jax.Array,         # (d,) fp32
-    d_min: jax.Array,
-    delta: jax.Array,
-    ew_map: jax.Array,    # (n_ew,) int32
-    m: int,
-    tau_pred: jax.Array,  # scalar int32
-    tile: int = TILE,
-    mc: int = MC,
-    interpret: bool | None = None,
-):
-    """Returns (est (n,), bucket (n,), hist (m+1,), early (n,), nmiss ())."""
-    interpret = resolve_interpret(interpret)
-    n, m_sub = codes.shape
-    d = vectors.shape[1]
-    g = n // tile
-    n_ew = ew_map.shape[0]
-    hist_pad = ((m + 1 + 127) // 128) * 128
-    scal = jnp.zeros((1, 128), jnp.float32)
-    scal = scal.at[0, 0].set(d_min.astype(jnp.float32))
-    scal = scal.at[0, 1].set(delta.astype(jnp.float32))
-    scal = scal.at[0, 2].set(tau_pred.astype(jnp.float32))
-    scal = scal.at[0, 3].set(jnp.sum(q * q))
-    w = valid.astype(jnp.int32)
-    est, bucket, early, hist, nmiss = pl.pallas_call(
-        functools.partial(_fused_kernel, m=m, hist_pad=hist_pad, mc=mc),
-        grid=(g,),
-        in_specs=[
-            pl.BlockSpec((tile, m_sub), lambda i: (i, 0)),
-            pl.BlockSpec((tile, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, tile), lambda i: (0, i)),
-            pl.BlockSpec(lut.shape, lambda i: (0, 0)),
-            pl.BlockSpec((1, d), lambda i: (0, 0)),
-            pl.BlockSpec((1, n_ew), lambda i: (0, 0)),
-            pl.BlockSpec((1, 128), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, tile), lambda i: (i, 0)),
-            pl.BlockSpec((1, tile), lambda i: (i, 0)),
-            pl.BlockSpec((1, tile), lambda i: (i, 0)),
-            pl.BlockSpec((1, hist_pad), lambda i: (0, 0)),
-            pl.BlockSpec((1, 128), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((g, tile), jnp.float32),
-            jax.ShapeDtypeStruct((g, tile), jnp.int32),
-            jax.ShapeDtypeStruct((g, tile), jnp.float32),
-            jax.ShapeDtypeStruct((1, hist_pad), jnp.int32),
-            jax.ShapeDtypeStruct((1, 128), jnp.int32),
-        ],
-        interpret=interpret,
-    )(codes, vectors, w.reshape(1, n), lut, q.reshape(1, d),
-      ew_map.reshape(1, n_ew), scal)
-    return (est.reshape(n), bucket.reshape(n), hist[0, : m + 1],
-            early.reshape(n), nmiss[0, 0])
 
 
 # --------------------------------------------------------------------------
@@ -191,29 +56,25 @@ def bucketize_hist_tile(est, w, ew, d_min, delta, m, hist_pad, bq):
     overflow = bin_f >= n_ew
     bin_id = jnp.clip(bin_f, 0, n_ew - 1).astype(jnp.int32)
 
-    def map_chunk(j, bucket):
-        bc = jax.lax.dynamic_slice_in_dim(bin_id, j * bq, bq, axis=1)
-        ewc = jax.lax.dynamic_slice_in_dim(ew, j * bq, bq, axis=0)
+    buckets = []
+    for j in range(b // bq):
+        bc = bin_id[:, j * bq:(j + 1) * bq]
+        ewc = ew[j * bq:(j + 1) * bq, :]
         iota = jax.lax.broadcasted_iota(jnp.int32, (tile, bq, n_ew), 2)
         onehot = (iota == bc[:, :, None]).astype(jnp.float32)
-        bkt = jnp.sum(onehot * ewc[None, :, :].astype(jnp.float32),
-                      axis=2).astype(jnp.int32)                  # (tile, bq)
-        return jax.lax.dynamic_update_slice_in_dim(bucket, bkt, j * bq, 1)
-
-    bucket = jax.lax.fori_loop(0, b // bq, map_chunk,
-                               jnp.zeros((tile, b), jnp.int32))
+        buckets.append(jnp.sum(onehot * ewc[None, :, :].astype(jnp.float32),
+                               axis=2).astype(jnp.int32))    # (tile, bq)
+    bucket = jnp.concatenate(buckets, axis=1)
     bucket = jnp.where(overflow, m, bucket)
 
-    def hist_chunk(j, hist):
-        bkt = jax.lax.dynamic_slice_in_dim(bucket, j * bq, bq, axis=1)
-        wc = jax.lax.dynamic_slice_in_dim(w, j * bq, bq, axis=1)
+    hists = []
+    for j in range(b // bq):
+        bkt = bucket[:, j * bq:(j + 1) * bq]
+        wc = w[:, j * bq:(j + 1) * bq]
         hiota = jax.lax.broadcasted_iota(jnp.int32, (tile, bq, hist_pad), 2)
         hoh = jnp.where(hiota == bkt[:, :, None], wc[:, :, None], 0)
-        hc = jnp.sum(hoh, axis=0, dtype=jnp.int32)               # (bq, hist_pad)
-        return jax.lax.dynamic_update_slice_in_dim(hist, hc, j * bq, 0)
-
-    hist = jax.lax.fori_loop(0, b // bq, hist_chunk,
-                             jnp.zeros((b, hist_pad), jnp.int32))
+        hists.append(jnp.sum(hoh, axis=0, dtype=jnp.int32))   # (bq, hist_pad)
+    hist = jnp.concatenate(hists, axis=0)
     return bucket, hist
 
 
@@ -231,25 +92,11 @@ def _fused_batch_kernel(codes_ref, vecs_ref, wmask_ref, luts_ref, qt_ref,
     d_min, delta = s[:, 0], s[:, 1]
     tau_pred = s[:, 2].astype(jnp.int32)
     q_sq = s[:, 3]
-    tile, m_sub = codes.shape
     b = w.shape[1]
-    k_codes = luts.shape[0] // m_sub
     inf = jnp.float32(jnp.inf)
 
     # --- ADC estimates for all B queries: chunked one-hot MXU matmul ---
-    def body(i, acc):
-        cs = jax.lax.dynamic_slice_in_dim(codes, i * mc, mc, axis=1)
-        ls = jax.lax.dynamic_slice_in_dim(luts, i * mc * k_codes,
-                                          mc * k_codes, axis=0)
-        iota = jax.lax.broadcasted_iota(jnp.int32, (tile, mc, k_codes), 2)
-        onehot = (iota == cs[:, :, None]).astype(jnp.float32)
-        part = jax.lax.dot_general(
-            onehot.reshape(tile, mc * k_codes), ls,
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        return acc + part                          # (tile, B)
-
-    est2 = jax.lax.fori_loop(0, m_sub // mc, body,
-                             jnp.zeros((tile, b), jnp.float32))
+    est2 = adc_tile(codes, luts, mc)
     est = jnp.sqrt(jnp.maximum(est2, 0.0))
     est = jnp.where(w > 0, est, inf)
     est_ref[...] = est
@@ -266,12 +113,8 @@ def _fused_batch_kernel(codes_ref, vecs_ref, wmask_ref, luts_ref, qt_ref,
 
     hist_ref[...] += tile_hist
 
-    # --- early exact for all B queries: one MXU matmul on the hot tile ---
-    xv = jax.lax.dot_general(vecs, qt, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # (tile, B)
-    x_sq = jnp.sum(vecs * vecs, axis=1)
-    exact = jnp.sqrt(jnp.maximum(
-        x_sq[:, None] - 2.0 * xv + q_sq[None, :], 0.0))
+    # --- early exact for all B queries: MXU matmuls on the hot tile ---
+    exact = exact_tile(vecs, qt, q_sq)
     pred = (w > 0) & (bucket <= tau_pred[None, :])
     early_ref[...] = jnp.where(pred, exact, inf)
 

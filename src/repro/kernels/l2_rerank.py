@@ -1,8 +1,14 @@
 """Pallas TPU kernel: tiled exact ||q - x|| for the re-rank pool.
 
-Straight MXU matvec per tile with the norm identity — the exact-distance
+Straight MXU matmul per tile with the norm identity — the exact-distance
 hot spot of every re-rank phase.  Included so the whole search inner loop
 (estimate -> bucketize -> select -> re-rank) runs on Pallas kernels.
+
+Every per-query matmul of the batched kernels goes through ``query_dot``:
+the queries are taken ``BQ`` columns at a time, each chunk its own matmul.
+Mosaic may lower a matmul differently at a different width, so a fixed
+chunk width keeps a query's estimates and distances bit-identical whatever
+batch it rides in (a served request and a direct call of the same query).
 """
 from __future__ import annotations
 
@@ -13,56 +19,41 @@ from jax.experimental import pallas as pl
 from repro.kernels.platform import resolve_interpret
 
 TILE = 256
+BQ = 8   # query chunk width of every per-query matmul; wrappers pad B to it
 
 
-def _l2_kernel(x_ref, q_ref, scal_ref, out_ref):
-    x = x_ref[...]                     # (TILE, d)
-    q = q_ref[...]                     # (1, d)
-    q_sq = scal_ref[...][0, 0]
-    xv = jax.lax.dot_general(
-        x, q.reshape(-1, 1), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)[:, 0]
+def query_dot(lhs: jax.Array, rhs: jax.Array) -> jax.Array:
+    """``lhs`` (r, c) @ ``rhs`` (c, B) -> (r, B) at full f32 precision, as
+    B // BQ matmuls of ``BQ`` query columns each (B % BQ == 0)."""
+    b = rhs.shape[1]
+    assert b % BQ == 0, (b, BQ)
+    outs = [jax.lax.dot_general(lhs, rhs[:, j * BQ:(j + 1) * BQ],
+                                (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32,
+                                precision=jax.lax.Precision.HIGHEST)
+            for j in range(b // BQ)]
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+
+
+def exact_tile(x: jax.Array, qt: jax.Array, q_sq: jax.Array) -> jax.Array:
+    """Exact ||q_b - x_i|| of one (tile, d) vector tile against the (d, B)
+    queries ``qt`` with squared norms ``q_sq`` (B,).  Returns (tile, B)."""
+    xv = query_dot(x, qt)
     x_sq = jnp.sum(x * x, axis=1)
-    out_ref[...] = jnp.sqrt(jnp.maximum(x_sq - 2.0 * xv + q_sq, 0.0))[None, :]
-
-
-def l2_pallas(x: jax.Array, q: jax.Array, tile: int = TILE,
-              interpret: bool | None = None) -> jax.Array:
-    interpret = resolve_interpret(interpret)
-    n, d = x.shape
-    g = n // tile
-    scal = jnp.zeros((1, 128), jnp.float32).at[0, 0].set(jnp.sum(q * q))
-    out = pl.pallas_call(
-        _l2_kernel,
-        grid=(g,),
-        in_specs=[
-            pl.BlockSpec((tile, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, d), lambda i: (0, 0)),
-            pl.BlockSpec((1, 128), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, tile), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((g, tile), jnp.float32),
-        interpret=interpret,
-    )(x, q.reshape(1, d), scal)
-    return out.reshape(n)
+    return jnp.sqrt(jnp.maximum(x_sq[:, None] - 2.0 * xv + q_sq[None, :],
+                                0.0))
 
 
 def _l2_batch_kernel(x_ref, qt_ref, scal_ref, out_ref):
-    x = x_ref[...]                     # (TILE, d)
-    qt = qt_ref[...]                   # (d, B)
-    q_sq = scal_ref[...][:, 0]         # (B,)
-    xv = jax.lax.dot_general(x, qt, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # (TILE, B)
-    x_sq = jnp.sum(x * x, axis=1)
-    out_ref[...] = jnp.sqrt(jnp.maximum(
-        x_sq[:, None] - 2.0 * xv + q_sq[None, :], 0.0))
+    out_ref[...] = exact_tile(x_ref[...], qt_ref[...], scal_ref[...][:, 0])
 
 
 def l2_batch_pallas(x: jax.Array, qs: jax.Array, tile: int = TILE,
                     interpret: bool | None = None) -> jax.Array:
-    """Exact ||q_b - x_i|| for a batch of queries: one MXU matmul per tile.
+    """Exact ||q_b - x_i|| for a batch of queries.
 
-    ``x`` (n, d) shared candidate vectors, ``qs`` (B, d).  Returns (B, n).
+    ``x`` (n, d) shared candidate vectors, ``qs`` (B, d) with B % BQ == 0
+    (the ops wrapper pads).  Returns (B, n).
     """
     interpret = resolve_interpret(interpret)
     n, d = x.shape

@@ -28,7 +28,6 @@ from repro.kernels import bucket_hist as _bh
 from repro.kernels import fused_scan as _fs
 from repro.kernels import l2_rerank as _l2
 from repro.kernels import pq_adc as _adc
-from repro.kernels import rabitq_est as _rq
 from repro.kernels import rabitq_fused as _rqf
 from repro.kernels import ref as _ref
 from repro.kernels import shard_collect as _sc
@@ -67,77 +66,6 @@ def _pad_cols(x: jax.Array, mult: int, fill) -> jax.Array:
     return jnp.pad(x, width, constant_values=fill)
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "mc"))
-def pq_adc(codes: jax.Array, lut: jax.Array, tile: int = _adc.TILE,
-           mc: int = _adc.MC) -> jax.Array:
-    """(n, M) codes, (M, K) LUT -> (n,) squared-distance estimates."""
-    n = codes.shape[0]
-    codes_p = _pad_cols(_pad_rows(codes.astype(jnp.int32), tile, 0), mc, 0)
-    lut_p = jnp.pad(lut, ((0, codes_p.shape[1] - lut.shape[0]), (0, 0)))
-    out = _adc.adc_pallas(codes_p, lut_p, tile=tile, mc=mc,
-                          interpret=_interpret())
-    return out[:n]
-
-
-@functools.partial(jax.jit, static_argnames=("eps0", "tile"))
-def rabitq_est(codes: jax.Array, norm_o: jax.Array, f_o: jax.Array,
-               v: jax.Array, norm_q: jax.Array, eps0: float = 3.0,
-               tile: int = _rq.TILE):
-    """±1 codes (n, d) -> (est, lb, ub), matching kernels.ref.rabitq_est."""
-    n, d = codes.shape
-    codes_p = _pad_cols(_pad_rows(codes, tile, 0), 128, 0)
-    v_p = jnp.pad(v, (0, codes_p.shape[1] - d))
-    norm_p = _pad_rows(norm_o, tile, 0.0)
-    f_p = _pad_rows(f_o, tile, 1.0)
-    est, lb, ub = _rq.rabitq_est_pallas(
-        codes_p, norm_p, f_p, v_p, norm_q, d_logical=d, eps0=eps0,
-        tile=tile, interpret=_interpret())
-    return est[:n], lb[:n], ub[:n]
-
-
-@functools.partial(jax.jit, static_argnames=("m", "tile"))
-def bucket_hist(dists: jax.Array, valid: jax.Array, d_min: jax.Array,
-                delta: jax.Array, ew_map: jax.Array, m: int,
-                tile: int = _bh.TILE):
-    """(n,) distances -> (bucket_ids (n,), hist (m+1,))."""
-    n = dists.shape[0]
-    d_p = _pad_rows(dists, tile, jnp.inf)
-    v_p = _pad_rows(valid, tile, False)
-    bucket, hist = _bh.bucket_hist_pallas(
-        d_p, v_p, d_min, delta, ew_map.astype(jnp.int32), m, tile=tile,
-        interpret=_interpret())
-    return bucket[:n], hist
-
-
-@functools.partial(jax.jit, static_argnames=("m", "tile", "mc"))
-def fused_scan(codes: jax.Array, vectors: jax.Array, valid: jax.Array,
-               lut: jax.Array, q: jax.Array, d_min: jax.Array,
-               delta: jax.Array, ew_map: jax.Array, m: int,
-               tau_pred: jax.Array, tile: int = _fs.TILE, mc: int = _fs.MC):
-    """Fused estimate+bucketize+hist+early-exact over a candidate block.
-
-    Returns (est (n,), bucket (n,), hist (m+1,), early (n,), nmiss ())."""
-    n, d = vectors.shape
-    codes_p = _pad_cols(_pad_rows(codes.astype(jnp.int32), tile, 0), mc, 0)
-    lut_p = jnp.pad(lut, ((0, codes_p.shape[1] - lut.shape[0]), (0, 0)))
-    vecs_p = _pad_cols(_pad_rows(vectors, tile, 0.0), 128, 0.0)
-    q_p = jnp.pad(q, (0, vecs_p.shape[1] - d))
-    valid_p = _pad_rows(valid, tile, False)
-    est, bucket, hist, early, nmiss = _fs.fused_scan_pallas(
-        codes_p, vecs_p, valid_p, lut_p, q_p, d_min, delta,
-        ew_map.astype(jnp.int32), m, tau_pred, tile=tile, mc=mc,
-        interpret=_interpret())
-    return est[:n], bucket[:n], hist, early[:n], nmiss
-
-
-@functools.partial(jax.jit, static_argnames=("tile",))
-def l2_exact(x: jax.Array, q: jax.Array, tile: int = _l2.TILE) -> jax.Array:
-    n, d = x.shape
-    x_p = _pad_cols(_pad_rows(x, tile, 0.0), 128, 0.0)
-    q_p = jnp.pad(q, (0, x_p.shape[1] - d))
-    return _l2.l2_pallas(x_p, q_p, tile=tile, interpret=_interpret())[:n]
-
-
 # --------------------------------------------------------------------------
 # Batched (multi-query) wrappers
 # --------------------------------------------------------------------------
@@ -155,12 +83,13 @@ def pq_adc_batch(codes: jax.Array, luts: jax.Array, tile: int = _adc.TILE,
     if backend == "ref":
         return _ref.pq_adc_batch(codes, luts)
     n = codes.shape[0]
+    b = luts.shape[0]
     codes_p = _pad_cols(_pad_rows(codes.astype(jnp.int32), tile, 0), mc, 0)
     m_pad = codes_p.shape[1] - luts.shape[1]
-    luts_p = jnp.pad(luts, ((0, 0), (0, m_pad), (0, 0)))
+    luts_p = jnp.pad(luts, ((0, _pad_batch(b, _adc.BQ)), (0, m_pad), (0, 0)))
     out = _adc.adc_batch_pallas(codes_p, luts_p, tile=tile, mc=mc,
                                 interpret=_interpret())
-    return out[:, :n]
+    return out[:b, :n]
 
 
 @functools.partial(jax.jit, static_argnames=("m", "tile", "mc", "backend"))
@@ -234,9 +163,10 @@ def fused_rabitq_scan_batch(codes: jax.Array, vectors: jax.Array,
     bp = _pad_batch(b, _rqf.BQ)
     codes_f = codes.astype(jnp.float32)
     # query-independent decomposition inputs (see ref.rabitq_bounds_stream)
-    h = centroids @ rot.T
+    h = jnp.matmul(centroids, rot.T, precision="highest")
     s2 = jnp.sum(codes_f * h[cl], axis=1)
-    g = qs @ rot.T
+    qs_b = jnp.pad(qs, ((0, bp), (0, 0)))
+    g = jnp.matmul(qs_b, rot.T, precision="highest")
     nq_lane = jnp.sqrt(d2)[:, cl]                              # (B, n)
     codes_p = _pad_cols(_pad_rows(codes_f, tile, 0.0), 128, 0.0)
     vecs_p = _pad_cols(_pad_rows(vectors, tile, 0.0), 128, 0.0)
@@ -247,8 +177,8 @@ def fused_rabitq_scan_batch(codes: jax.Array, vectors: jax.Array,
     valid_p = jnp.pad(_pad_cols(valid, tile, False), ((0, bp), (0, 0)))
     nq_p = jnp.pad(_pad_cols(nq_lane, tile, 1.0), ((0, bp), (0, 0)),
                    constant_values=1.0)
-    g_p = jnp.pad(g, ((0, bp), (0, dp)))
-    qs_p = jnp.pad(qs, ((0, bp), (0, dp)))
+    g_p = jnp.pad(g, ((0, 0), (0, dp)))
+    qs_p = jnp.pad(qs_b, ((0, 0), (0, dp)))
     d_min_p = jnp.pad(d_min, (0, bp))
     delta_p = jnp.pad(delta, (0, bp), constant_values=1.0)
     ew_p = jnp.pad(ew_maps.astype(jnp.int32), ((0, bp), (0, 0)))
@@ -261,25 +191,6 @@ def fused_rabitq_scan_batch(codes: jax.Array, vectors: jax.Array,
     return (est[:b, :n], lb[:b, :n], ub[:b, :n], blb[:b, :n], bub[:b, :n],
             hist_lb[:b], hist_ub[:b], exact[:b, :n], cert[:b, :n],
             nmiss[:b])
-
-
-@functools.partial(jax.jit, static_argnames=("m", "eps0", "tile", "backend"))
-def fused_rabitq_scan(codes: jax.Array, vectors: jax.Array,
-                      norm_o: jax.Array, f_o: jax.Array, cl: jax.Array,
-                      centroids: jax.Array, rot: jax.Array, q: jax.Array,
-                      d2: jax.Array, valid: jax.Array, d_min: jax.Array,
-                      delta: jax.Array, ew_map: jax.Array, m: int,
-                      tau_inline: jax.Array, eps0: float = 3.0,
-                      tile: int = _rqf.TILE, backend: str | None = None):
-    """Single-query bound-fused RaBitQ scan: the batched kernel on a
-    singleton batch (the batched formulation is the native one — a single
-    query is just B == 1)."""
-    outs = fused_rabitq_scan_batch(
-        codes, vectors, norm_o, f_o, cl, centroids, rot, q[None], d2[None],
-        valid[None], jnp.asarray(d_min)[None], jnp.asarray(delta)[None],
-        ew_map[None], m, jnp.asarray(tau_inline, jnp.int32)[None],
-        eps0=eps0, tile=tile, backend=backend)
-    return tuple(o[0] for o in outs)
 
 
 @functools.partial(jax.jit, static_argnames=("m", "tile", "backend"))
@@ -374,7 +285,9 @@ def l2_exact_batch(x: jax.Array, qs: jax.Array, tile: int = _l2.TILE,
     if backend == "ref":
         return _ref.l2_exact_batch(x, qs)
     n, d = x.shape
+    b = qs.shape[0]
+    bp = _pad_batch(b, _l2.BQ)
     x_p = _pad_cols(_pad_rows(x, tile, 0.0), 128, 0.0)
-    qs_p = jnp.pad(qs, ((0, 0), (0, x_p.shape[1] - d)))
+    qs_p = jnp.pad(qs, ((0, bp), (0, x_p.shape[1] - d)))
     return _l2.l2_batch_pallas(x_p, qs_p, tile=tile,
-                               interpret=_interpret())[:, :n]
+                               interpret=_interpret())[:b, :n]

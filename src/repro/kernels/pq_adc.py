@@ -3,12 +3,15 @@
 CPU FastScan uses AVX shuffles to look 16-entry LUTs up for 16 codes at once.
 The MXU analogue recasts the lookup as a one-hot matmul:
 
-    est[n] = sum_m LUT[m, code[n, m]]
-           = reshape(onehot(codes), (TILE, M*K)) @ reshape(LUT, (M*K, 1))
+    est[n, b] = sum_m LUT_b[m, code[n, m]]
+              = onehot(codes) (TILE, M*K) @ LUT (M*K, B)
 
-The one-hot tensor is built in VMEM in M-chunks of ``mc`` sub-quantizers so the
-working set stays bounded: (TILE, mc, K) fp32 = 256*32*16*4 = 512 KiB per
-chunk at the default tile, well inside VMEM alongside the code block.
+The one-hot tile is built in VMEM in M-chunks of ``mc`` sub-quantizers so the
+working set stays bounded: (TILE, mc*K) fp32 = 256*512*4 = 512 KiB per chunk
+at the default tile.  It is built with 2-D operations only (Mosaic lowers no
+3-D reshape cheaply): a 0/1 expansion matmul repeats each code K times along
+the lanes, and a compare against the code value each lane tests for gives the
+one-hot.
 
 Tiling: grid over row tiles of ``TILE`` codes; LUT replicated to every step
 (index_map -> (0, 0)); code block (TILE, M) streams HBM->VMEM.
@@ -21,95 +24,65 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.l2_rerank import BQ, query_dot
 from repro.kernels.platform import resolve_interpret
 
 TILE = 256
 MC = 32  # sub-quantizer chunk
 
 
-def _adc_kernel(codes_ref, lut_ref, out_ref, *, mc: int):
-    codes = codes_ref[...].astype(jnp.int32)         # (TILE, M)
-    lut = lut_ref[...]                               # (M, K)
-    tile, m_sub = codes.shape
-    k_codes = lut.shape[1]
-    n_chunks = m_sub // mc
-
-    def body(i, acc):
-        cs = jax.lax.dynamic_slice_in_dim(codes, i * mc, mc, axis=1)
-        ls = jax.lax.dynamic_slice_in_dim(lut, i * mc, mc, axis=0)
-        iota = jax.lax.broadcasted_iota(jnp.int32, (tile, mc, k_codes), 2)
-        onehot = (iota == cs[:, :, None]).astype(ls.dtype)
-        part = jax.lax.dot_general(
-            onehot.reshape(tile, mc * k_codes),
-            ls.reshape(mc * k_codes, 1),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return acc + part[:, 0]
-
-    acc = jax.lax.fori_loop(
-        0, n_chunks, body, jnp.zeros((tile,), lut_ref.dtype))
-    out_ref[...] = acc[None, :]
-
-
-def adc_pallas(codes: jax.Array, lut: jax.Array, *, tile: int = TILE,
-               mc: int = MC, interpret: bool | None = None) -> jax.Array:
-    """(n, M) codes + (M, K) LUT -> (n,) squared-distance estimates.
-
-    Caller guarantees n % tile == 0 and M % mc == 0 (ops.py pads).
-    """
-    interpret = resolve_interpret(interpret)
-    n, m_sub = codes.shape
-    grid = (n // tile,)
-    out = pl.pallas_call(
-        functools.partial(_adc_kernel, mc=mc),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile, m_sub), lambda i: (i, 0)),
-            pl.BlockSpec(lut.shape, lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, tile), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n // tile, tile), lut.dtype),
-        interpret=interpret,
-    )(codes, lut)
-    return out.reshape(n)
-
-
 # --------------------------------------------------------------------------
 # Batched (multi-query) ADC
 # --------------------------------------------------------------------------
 
-def _adc_batch_kernel(codes_ref, luts_ref, out_ref, *, mc: int):
-    codes = codes_ref[...].astype(jnp.int32)         # (TILE, M)
-    luts = luts_ref[...]                             # (M*K, B)
+def adc_tile(codes: jax.Array, luts: jax.Array, mc: int) -> jax.Array:
+    """Squared ADC estimates of one code tile for every query.
+
+    ``codes`` (tile, M) int32 with M % mc == 0, ``luts`` (M*K, B) fp32 (row
+    ``m*K + c`` holds sub-quantizer m's distance to centroid c), B % BQ ==
+    0.  Returns (tile, B).
+    """
     tile, m_sub = codes.shape
-    b = luts.shape[1]
     k_codes = luts.shape[0] // m_sub
+    width = mc * k_codes
+    row = jax.lax.broadcasted_iota(jnp.int32, (mc, width), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (mc, width), 1)
+    # expand[r, j] = 1 where one-hot column j belongs to sub-quantizer r
+    expand = ((col >= row * k_codes)
+              & (col < (row + 1) * k_codes)).astype(jnp.float32)
+    # the code value column j tests for: j - K * (j // K)
+    starts = (k_codes * jax.lax.broadcasted_iota(
+        jnp.int32, (1, mc), 1)).astype(jnp.float32)
+    want = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1).astype(
+        jnp.float32) - jax.lax.dot_general(
+            starts, expand, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST)
+    acc = jnp.zeros((tile, luts.shape[1]), jnp.float32)
+    for i in range(m_sub // mc):
+        cs = codes[:, i * mc:(i + 1) * mc].astype(jnp.float32)
+        # codes are < K <= 256: exact at any matmul precision
+        rep = jax.lax.dot_general(cs, expand, (((1,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        onehot = (rep == want).astype(jnp.float32)            # (tile, width)
+        acc = acc + query_dot(onehot, luts[i * width:(i + 1) * width, :])
+    return acc
 
-    def body(i, acc):
-        cs = jax.lax.dynamic_slice_in_dim(codes, i * mc, mc, axis=1)
-        ls = jax.lax.dynamic_slice_in_dim(luts, i * mc * k_codes,
-                                          mc * k_codes, axis=0)
-        iota = jax.lax.broadcasted_iota(jnp.int32, (tile, mc, k_codes), 2)
-        onehot = (iota == cs[:, :, None]).astype(jnp.float32)
-        part = jax.lax.dot_general(
-            onehot.reshape(tile, mc * k_codes), ls,
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        return acc + part                            # (TILE, B)
 
-    acc = jax.lax.fori_loop(0, m_sub // mc, body,
-                            jnp.zeros((tile, b), jnp.float32))
-    out_ref[...] = acc
+def _adc_batch_kernel(codes_ref, luts_ref, out_ref, *, mc: int):
+    out_ref[...] = adc_tile(codes_ref[...].astype(jnp.int32), luts_ref[...],
+                            mc)
 
 
 def adc_batch_pallas(codes: jax.Array, luts: jax.Array, *, tile: int = TILE,
                      mc: int = MC,
                      interpret: bool | None = None) -> jax.Array:
     """Shared (n, M) codes x per-query (B, M, K) LUTs -> (B, n) squared
-    estimates: one code-block stream, ADC for all B queries as a single MXU
-    matmul per chunk.
+    estimates: one code-block stream, ADC for every query as MXU matmuls
+    against the resident one-hot chunk.
 
-    Caller guarantees n % tile == 0 and M % mc == 0 (ops.py pads).
+    Caller guarantees n % tile == 0, M % mc == 0 and B % BQ == 0 (ops.py
+    pads).
     """
     interpret = resolve_interpret(interpret)
     n, m_sub = codes.shape

@@ -10,8 +10,8 @@ counts).  The fused kernel streams the ±1 code block AND the fp32 vector
 block of a cluster tile through VMEM together and, per tile, produces
 
     est/lb/ub   — the RaBitQ estimator with its error bounds (the batched
-                  ``P(q-c) = Pq - Pc`` decomposition: one (TILE, d) x (d, B)
-                  MXU matmul against the rotated queries plus a per-lane
+                  ``P(q-c) = Pq - Pc`` decomposition: (TILE, d) x (d, BQ)
+                  MXU matmuls against the rotated queries plus a per-lane
                   centroid correction ``s2`` that is query-independent),
     bucket_lb / bucket_ub — Eq. 6 bucket ids of both bounds against the
                   per-query codebook (one-hot LUT, shared helper with the
@@ -52,10 +52,10 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.kernels.fused_scan import bucketize_hist_tile
+from repro.kernels.l2_rerank import BQ, exact_tile, query_dot
 from repro.kernels.platform import resolve_interpret
 
 TILE = 256
-BQ = 8   # query-batch chunk width inside the bucketize/hist helper
 
 
 def _rabitq_fused_batch_kernel(codes_ref, vecs_ref, s2_ref, norm_ref, f_ref,
@@ -82,9 +82,8 @@ def _rabitq_fused_batch_kernel(codes_ref, vecs_ref, s2_ref, norm_ref, f_ref,
     tile, b = w.shape
     inf = jnp.float32(jnp.inf)
 
-    # --- RaBitQ estimator + bounds: one MXU matmul for all B queries ---
-    s1 = jax.lax.dot_general(codes, g, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # (TILE, B)
+    # --- RaBitQ estimator + bounds: MXU matmuls against the code tile ---
+    s1 = query_dot(codes, g)                                  # (TILE, B)
     xv = (s1 - s2[:, None]) / (sqrt_d * jnp.maximum(nq, 1e-12))
     ip = xv / fo[:, None]
     err = eps0 * jnp.sqrt((1.0 - fo * fo) / (fo * fo * dm1))      # (TILE,)
@@ -120,11 +119,7 @@ def _rabitq_fused_batch_kernel(codes_ref, vecs_ref, s2_ref, norm_ref, f_ref,
     hist_ub_ref[...] += tile_hist_ub
 
     # --- bound-certified inline exact: vectors are already in VMEM ---
-    xq = jax.lax.dot_general(vecs, qt, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # (TILE, B)
-    x_sq = jnp.sum(vecs * vecs, axis=1)
-    exact = jnp.sqrt(jnp.maximum(
-        x_sq[:, None] - 2.0 * xq + q_sq[None, :], 0.0))
+    exact = exact_tile(vecs, qt, q_sq)
     cert = live & (bucket_lb <= tau_inline[None, :])
     exact_ref[...] = jnp.where(cert, exact, inf)
     cert_ref[...] = cert.astype(jnp.int32)

@@ -12,27 +12,6 @@ def pq_adc(codes: jax.Array, lut: jax.Array) -> jax.Array:
     return jnp.sum(take, axis=1)
 
 
-def rabitq_est(
-    codes: jax.Array,   # (n, d) int8 {-1,+1}
-    norm_o: jax.Array,  # (n,)
-    f_o: jax.Array,     # (n,)
-    v: jax.Array,       # (d,) rotated unit query residual
-    norm_q: jax.Array,  # scalar
-    eps0: float = 3.0,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    d = codes.shape[1]
-    xv = (codes.astype(jnp.float32) @ v) / jnp.sqrt(jnp.float32(d))
-    ip = xv / f_o
-    err = eps0 * jnp.sqrt((1.0 - f_o ** 2) / (f_o ** 2 * (d - 1)))
-    scale = 2.0 * norm_q * norm_o
-    base = norm_q ** 2 + norm_o ** 2
-    z = jnp.zeros_like(base)
-    est = jnp.sqrt(jnp.maximum(base - scale * ip, z))
-    lb = jnp.sqrt(jnp.maximum(base - scale * (ip + err), z))
-    ub = jnp.sqrt(jnp.maximum(base - scale * (ip - err), z))
-    return est, lb, ub
-
-
 def bucketize(dists: jax.Array, d_min: jax.Array, delta: jax.Array,
               ew_map: jax.Array, m: int) -> jax.Array:
     """Eq. 6 bucket ids with overflow bucket m."""
@@ -54,8 +33,9 @@ def bucket_hist(dists: jax.Array, valid: jax.Array, d_min, delta,
 
 def l2_exact(x: jax.Array, q: jax.Array) -> jax.Array:
     """Exact Euclidean distance of rows of x to q."""
+    xq = jnp.matmul(x, q, precision="highest")
     return jnp.sqrt(jnp.maximum(
-        jnp.sum(x * x, -1) - 2.0 * (x @ q) + jnp.sum(q * q), 0.0))
+        jnp.sum(x * x, -1) - 2.0 * xq + jnp.sum(q * q), 0.0))
 
 
 # --------------------------------------------------------------------------
@@ -177,7 +157,7 @@ def l2_exact_batch(x: jax.Array, qs: jax.Array) -> jax.Array:
     one norm-identity matmul."""
     x_sq = jnp.sum(x * x, axis=-1)
     q_sq = jnp.sum(qs * qs, axis=-1)
-    xv = qs @ x.T
+    xv = jnp.matmul(qs, x.T, precision="highest")
     return jnp.sqrt(jnp.maximum(
         x_sq[None, :] - 2.0 * xv + q_sq[:, None], 0.0))
 
@@ -229,9 +209,9 @@ def rabitq_bounds_stream(codes_s: jax.Array, norm_o: jax.Array,
     squared query-centroid distance matrix the routing pass already built;
     ``cl`` maps each stream lane to its (clamped) owning cluster.
     """
-    g = qs @ rot.T                                            # (B, d) = Pq
-    h = centroids @ rot.T                                     # (C, d) = Pc
-    s1 = codes_s @ g.T                                        # (n_stream, B)
+    g = jnp.matmul(qs, rot.T, precision="highest")            # (B, d) = Pq
+    h = jnp.matmul(centroids, rot.T, precision="highest")     # (C, d) = Pc
+    s1 = jnp.matmul(codes_s, g.T, precision="highest")    # (n_stream, B)
     s2 = jnp.sum(codes_s * h[cl], axis=1)                     # (n_stream,)
     nq = jnp.sqrt(d2)                                         # (B, C) norm_q
     nq_lane = nq[:, cl]                                       # (B, n_stream)
@@ -294,17 +274,6 @@ def fused_rabitq_scan_batch(
     nmiss = jnp.sum(valid & ~certified, axis=1).astype(jnp.int32)
     return (est, lb, ub, bucket_lb, bucket_ub, hist_lb, hist_ub, exact,
             certified, nmiss)
-
-
-def fused_rabitq_scan(codes_s, vectors, norm_o, f_o, cl, centroids, rot,
-                      q, d2, valid, d_min, delta, ew_map, m, tau_inline,
-                      eps0: float = 3.0):
-    """Single-query oracle: the batched mirror on a singleton batch."""
-    outs = fused_rabitq_scan_batch(
-        codes_s, vectors, norm_o, f_o, cl, centroids, rot, q[None],
-        d2[None], valid[None], d_min[None], delta[None], ew_map[None], m,
-        jnp.asarray(tau_inline, jnp.int32)[None], eps0)
-    return tuple(o[0] for o in outs)
 
 
 def fused_scan(

@@ -26,10 +26,11 @@ path degrades to exactly the old behavior.
 Compaction inside the kernel: per tile the masked lanes' prefix sums give
 their slots; a (tile, tile) slot==prefix one-hot reduce scatters the global
 lane positions into a compacted (tile,) vector (each slot matches at most
-one lane), which is written at the buffer's current fill offset with a
-dynamic lane-window store.  The buffer is ``budget + tile`` wide so a
-partially-filling window never clips; empty window tails hold the sentinel
-``n_pad`` and are overwritten by the next tile's window.
+one lane), which is written at the buffer's current fill offset: a
+(tile + 128)-lane window store at the 128-aligned base below the offset,
+the compacted row rotated into place.  The buffer is ``budget + tile + 128``
+wide so a partially-filling window never clips; empty window tails hold the
+sentinel ``n_pad`` and are overwritten by the next tile's window.
 
 Grid accumulation (histogram, fill counts, buffer) relies on Pallas TPU
 grids iterating sequentially on a core, exactly like bucket_hist.py.
@@ -41,6 +42,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.fused_scan import bucketize_hist_tile
 from repro.kernels.platform import resolve_interpret
@@ -53,25 +55,57 @@ def _compact_tile(bucket, w, tau_spec, spec_ref, cnt_ref, budget: int,
                   n_pad: int):
     """Append this tile's at-or-below-``tau_spec`` lanes to the resident
     survivor buffer, in stream order.  ``bucket``/``w`` are (tile, b);
-    ``spec_ref`` is the (b, budget + tile) position buffer, ``cnt_ref`` the
-    (b, 128) running fill counts (col 0; kept as the TRUE unclamped totals
-    so the wrapper can report them — only the write offset clamps)."""
+    ``spec_ref`` is the (b, budget + tile + 128) position buffer,
+    ``cnt_ref`` the (b, 128) running fill counts (col 0; kept as the TRUE
+    unclamped totals so the wrapper can report them — only the write
+    offset clamps)."""
     tile, b = bucket.shape
     specm = (w > 0) & (bucket <= tau_spec[None, :])
     mi = specm.astype(jnp.int32)
-    pref = jnp.cumsum(mi, axis=0) - 1                        # (tile, b)
+    # in-tile prefix count as a lower-triangular matmul (exact: 0/1 inputs,
+    # sums <= tile)
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
+           >= jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1))
+    pref = jax.lax.dot_general(
+        tri.astype(jnp.float32), mi.astype(jnp.float32),
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST).astype(jnp.int32) - 1  # (tile, b)
     tile_counts = jnp.sum(mi, axis=0)                        # (b,)
+    # each lane's slot in its query's compacted row; `tile` = no slot
+    slots = jnp.where(specm, pref, tile).astype(jnp.float32)  # (tile, b)
     gpos = pl.program_id(0) * tile + jax.lax.broadcasted_iota(
-        jnp.int32, (tile, 1), 0)[:, 0]                       # (tile,)
-    sio = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
-    for q in range(b):
-        slots_q = jnp.where(specm[:, q], pref[:, q], tile)   # (tile,)
-        eq = sio == slots_q[None, :]                         # eq[slot, lane]
-        compact = jnp.sum(jnp.where(eq, gpos[None, :], 0), axis=1)
-        filled = jnp.sum(eq.astype(jnp.int32), axis=1)
-        compact = jnp.where(filled > 0, compact, n_pad)
+        jnp.int32, (tile, 1), 0)                             # (tile, 1)
+    slot_ids = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1).astype(
+        jnp.float32)
+    qids = jax.lax.broadcasted_iota(jnp.int32, (b, 1), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile + 128), 1)
+    pad = jnp.full((1, 128), n_pad, jnp.int32)
+
+    def one_query(q, carry):
+        # column q of `slots` (a one-hot matmul: Mosaic slices no lane
+        # dimension at a traced offset); values <= tile are exact
+        slots_q = jax.lax.dot_general(
+            slots, (qids == q).astype(jnp.float32), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST)             # (tile, 1)
+        eq = slots_q == slot_ids                             # eq[lane, slot]
+        compact = jnp.sum(jnp.where(eq, gpos, 0), axis=0, keepdims=True)
+        filled = jnp.sum(eq.astype(jnp.int32), axis=0, keepdims=True)
+        compact = jnp.where(filled > 0, compact, n_pad)      # (1, tile)
+        # Mosaic stores lane windows only at 128-aligned offsets: write the
+        # (tile + 128)-lane window at the aligned base below the fill offset,
+        # with the compacted row rotated right by the remainder and the
+        # window's leading lanes (already-filled slots) kept as they were
         off = jnp.minimum(cnt_ref[q, 0], budget)
-        spec_ref[q, pl.ds(off, tile)] = compact
+        base = pl.multiple_of((off // 128) * 128, 128)
+        rem = off - base
+        win = spec_ref[pl.ds(q, 1), pl.ds(base, tile + 128)]
+        shifted = pltpu.roll(jnp.concatenate([compact, pad], axis=1), rem, 1)
+        spec_ref[pl.ds(q, 1), pl.ds(base, tile + 128)] = jnp.where(
+            lane < rem, win, shifted)
+        return carry
+
+    jax.lax.fori_loop(0, b, one_query, 0)
     cio = jax.lax.broadcasted_iota(jnp.int32, (b, 128), 1)
     cnt_ref[...] += jnp.where(cio == 0, tile_counts[:, None], 0)
 
@@ -129,7 +163,7 @@ def shard_collect_batch_pallas(
     n_ew = ew_maps.shape[1]
     hist_pad = ((m + 1 + 127) // 128) * 128
     bud_pad = ((budget + 127) // 128) * 128
-    spec_w = bud_pad + tile
+    spec_w = bud_pad + tile + 128
     scal = jnp.zeros((b, 128), jnp.float32)
     scal = scal.at[:, 0].set(d_min.astype(jnp.float32))
     scal = scal.at[:, 1].set(delta.astype(jnp.float32))
@@ -192,7 +226,7 @@ def spec_compact_batch_pallas(
     b, n = bucket.shape
     g = n // tile
     bud_pad = ((budget + 127) // 128) * 128
-    spec_w = bud_pad + tile
+    spec_w = bud_pad + tile + 128
     taus = jnp.broadcast_to(tau_spec.astype(jnp.int32)[:, None],
                             (b, 128))
     w = valid.astype(jnp.int32).T

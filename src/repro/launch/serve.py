@@ -57,6 +57,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import subprocess
 import sys
 import time
 
@@ -102,6 +103,7 @@ import numpy as np
 
 from repro.data import synthetic
 from repro.index import engine, flat, search
+from repro.launch import compile_cache
 
 
 METHODS = ("ivfpq", "ivfpq_bbc", "ivfrabitq", "ivfrabitq_bbc", "flat")
@@ -336,6 +338,20 @@ def _parse_net_addr(spec: str):
                          f"'host:port'")
 
 
+def _device_census() -> tuple[str, int]:
+    """(platform, device count) as a fresh process sees them.  A child
+    answers, so this process initializes no backend: on a TPU host the
+    first process to touch the chips holds them until it exits."""
+    probe = ("import jax; ds = jax.devices(); "
+             "print(ds[0].platform, len(ds))")
+    out = subprocess.run([sys.executable, "-c", probe], capture_output=True,
+                         text=True, timeout=300, env=dict(os.environ))
+    if out.returncode != 0:
+        raise SystemExit(f"device probe failed:\n{out.stderr[-2000:]}")
+    platform, count = out.stdout.split()[-2:]
+    return platform, int(count)
+
+
 def run_net(args):
     """The multi-process socket front-end (``repro.transport``)."""
     import signal
@@ -351,6 +367,16 @@ def run_net(args):
     from repro.transport.enginehost import build_spec, make_dataset
     from repro.transport.master import MasterServer
 
+    # one worker process per device; the master itself serves nothing and
+    # stays on the CPU, so the workers can take the accelerators
+    platform, n_devices = _device_census()
+    if platform != "cpu" and args.workers > n_devices:
+        raise SystemExit(
+            f"--workers {args.workers}: this host has {n_devices} "
+            f"{platform} device(s), and each worker process needs one of "
+            f"its own")
+    jax.config.update("jax_platforms", "cpu")
+
     ks = tuple(int(s) for s in args.k_choices.split(",")) \
         if args.k_choices else (args.k,)
     n_clusters = min(args.n_clusters, max(args.n // 64, 16))
@@ -363,7 +389,9 @@ def run_net(args):
                        cache_size=args.net_cache,
                        hb_interval=args.hb_ms / 1e3)
     ms = MasterServer(cfg, spec, addr=_parse_net_addr(args.addr), wire=wire,
-                      record=bool(args.record) or args.check_replay)
+                      record=bool(args.record) or args.check_replay,
+                      device_per_worker=platform == "tpu"
+                      and args.workers > 1)
     t0 = time.monotonic()
     ms.start()
     if not ms.wait_workers(timeout=300.0):
@@ -371,7 +399,8 @@ def run_net(args):
         ms.shutdown()
         return 1
     print(f"[serve] {args.workers} workers ready in "
-          f"{time.monotonic()-t0:.1f}s on {ms.addr}", flush=True)
+          f"{time.monotonic()-t0:.1f}s on {ms.addr}; devices "
+          f"{json.dumps(ms.worker_devices, sort_keys=True)}", flush=True)
 
     want_drain = threading.Event()
     signal.signal(signal.SIGTERM, lambda s, f: want_drain.set())
@@ -574,6 +603,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0,
                     help="trace/corpus RNG seed")
     args = ap.parse_args()
+    compile_cache.enable()
 
     if args.mode == "net":
         sys.exit(run_net(args))

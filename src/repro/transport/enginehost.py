@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.data import synthetic
+from repro.index import ivf as ivf_mod
 from repro.index import search as idx_search
 from repro.serving.batcher import ShapeBucket, bucket_of, k_ceilings
 from repro.serving.server import trim_topk
@@ -51,6 +52,17 @@ def make_dataset(spec: dict) -> np.ndarray:
     if kind == "isotropic":
         return synthetic.isotropic(rng, n, d)
     return synthetic.manifold(rng, n, d)
+
+
+def centroids_from_spec(spec: dict) -> np.ndarray:
+    """The spec's coarse centroids alone — the IVF leg of
+    ``build_state_from_spec``'s index build, with the same key split — for a
+    process that routes requests but serves none (the net master)."""
+    x = jnp.asarray(make_dataset(spec))
+    k_ivf, _ = jax.random.split(jax.random.key(int(spec["seed"])))
+    ivf = ivf_mod.build(k_ivf, x, int(spec["n_clusters"]),
+                        int(spec["n_iter"]))
+    return np.asarray(ivf.centroids)
 
 
 def build_state_from_spec(spec: dict) -> tuple[ServingState, tuple[int, ...]]:

@@ -42,7 +42,7 @@ from repro.serving import faults as flt
 from repro.serving.clock import Clock, SystemClock
 from repro.transport import frames
 from repro.transport.core import MasterConfig, MasterCore
-from repro.transport.enginehost import build_state_from_spec
+from repro.transport.enginehost import centroids_from_spec
 from repro.transport.wire import Transcript, WireShim
 
 
@@ -68,6 +68,12 @@ class _Conn:
         self.last_rx = 0.0
 
 
+def _free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("localhost", 0))
+        return s.getsockname()[1]
+
+
 class MasterServer:
     """Wall-clock front-end over one :class:`MasterCore`."""
 
@@ -77,7 +83,8 @@ class MasterServer:
                  clock: Clock | None = None, run_dir: str | None = None,
                  spawn_workers: bool = True, respawn: bool = True,
                  conn_idle_timeout: float = 30.0,
-                 drain_timeout: float = 10.0):
+                 drain_timeout: float = 10.0,
+                 device_per_worker: bool = False):
         self.cfg = cfg
         self.spec = dict(spec)
         self.codec = codec or frames.default_codec()
@@ -90,8 +97,12 @@ class MasterServer:
         self.respawn = respawn
         self.conn_idle_timeout = float(conn_idle_timeout)
         self.drain_timeout = float(drain_timeout)
-        state, _ = build_state_from_spec(spec)
-        self.core = MasterCore(cfg, state.centroids)
+        # several workers on one TPU host: each sees one chip of its own
+        self.device_per_worker = bool(device_per_worker)
+        # the master routes but never serves: it needs the centroids only,
+        # and builds no engine (a serving engine would hold the device the
+        # workers need)
+        self.core = MasterCore(cfg, centroids_from_spec(spec))
         self.transcript = Transcript() if record else None
         self.sel = selectors.DefaultSelector()
         self.listener: socket.socket | None = None
@@ -99,6 +110,8 @@ class MasterServer:
         self._cid = itertools.count(1)
         self.worker_conn: dict[int, _Conn] = {}     # wid -> live conn
         self.procs: dict[int, subprocess.Popen] = {}
+        # the devices each worker's READY frame reports, by worker id
+        self.worker_devices: dict[int, list[str]] = {}
         self._respawned: set[int] = set()
         self._timers: list = []                     # (t, seq, payload)
         self._tseq = itertools.count()
@@ -143,9 +156,19 @@ class MasterServer:
         with open(path, "w") as f:
             json.dump(self._worker_spec(wid), f)
         log = open(os.path.join(self.run_dir, f"worker{wid}.log"), "ab")
+        env = dict(os.environ)
+        if self.device_per_worker:
+            # chip `wid` as a one-chip slice of its own; its slice port is
+            # a free one, named as the slice's only address
+            port = _free_port()
+            env.update(TPU_VISIBLE_CHIPS=str(wid),
+                       TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1",
+                       TPU_PROCESS_BOUNDS="1,1,1",
+                       TPU_PROCESS_PORT=str(port),
+                       TPU_PROCESS_ADDRESSES=f"localhost:{port}")
         self.procs[wid] = subprocess.Popen(
             [sys.executable, "-m", "repro.transport.worker", path],
-            stdout=log, stderr=log, env=dict(os.environ))
+            stdout=log, stderr=log, env=env)
         log.close()
 
     # -- recording + core feed -----------------------------------------------
@@ -313,6 +336,7 @@ class MasterServer:
         wid = conn.wid
         kind = frame.get("kind")
         if kind == frames.READY:
+            self.worker_devices[wid] = list(frame.get("devices") or [])
             self._feed({"ev": "up", "t": now, "wid": wid,
                         "respawned": wid in self._respawned,
                         "svc": frame.get("svc") or {}})
@@ -536,11 +560,21 @@ class MasterServer:
     # -- convenience ---------------------------------------------------------
 
     def wait_workers(self, timeout: float = 60.0) -> bool:
-        """Serve until every worker has connected and sent READY."""
+        """Serve until every worker has connected and sent READY.  A worker
+        that exits first is not respawned: a start-up failure (no device
+        for it, a spec it cannot build) would repeat, so the wait ends at
+        once and returns False."""
         t_end = self.clock.now() + timeout
 
+        def died():
+            return any(p.poll() is not None for p in self.procs.values())
+
         def ready():
-            return all(w.connected for w in self.core.workers) or \
-                self.clock.now() > t_end
-        self.serve(until=ready)
+            return all(w.connected for w in self.core.workers) or died() \
+                or self.clock.now() > t_end
+        respawn, self.respawn = self.respawn, False
+        try:
+            self.serve(until=ready)
+        finally:
+            self.respawn = respawn
         return all(w.connected for w in self.core.workers)

@@ -30,8 +30,10 @@ import socket
 import sys
 import time
 
+import jax
 import numpy as np
 
+from repro.launch import compile_cache
 from repro.serving import faults as flt
 from repro.transport import frames
 from repro.transport.enginehost import (build_state_from_spec, make_exec_fn,
@@ -68,6 +70,7 @@ class WorkerApp:
         self.exec_fn = make_exec_fn(state, self.ceilings)
         self.svc = warmup_and_measure(self.exec_fn, spec["engine"],
                                       self.ceilings)
+        self.devices = [str(d) for d in jax.devices()]
         self.served = 0
 
     # -- one request ---------------------------------------------------------
@@ -114,7 +117,8 @@ class WorkerApp:
             {"kind": frames.HELLO, "role": "worker", "wid": self.wid},
             codec))
         sock.sendall(frames.encode_frame(
-            {"kind": frames.READY, "wid": self.wid, "svc": self.svc},
+            {"kind": frames.READY, "wid": self.wid, "svc": self.svc,
+             "devices": self.devices},
             codec))
         reader = frames.FrameReader()
         sock.settimeout(self.hb_interval / 2)
@@ -178,6 +182,7 @@ def main(argv: list[str]) -> int:
         print("usage: python -m repro.transport.worker <spec.json>",
               file=sys.stderr)
         return 2
+    compile_cache.enable()
     with open(argv[1]) as f:
         spec = json.load(f)
     app = WorkerApp(spec)

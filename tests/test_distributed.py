@@ -21,11 +21,7 @@ SCRIPT = textwrap.dedent(
     import numpy as np
     from jax.sharding import PartitionSpec as P
     from functools import partial
-    if hasattr(jax, "shard_map"):                    # jax >= 0.6
-        shard_map = partial(jax.shard_map, check_vma=False)
-    else:                                            # jax 0.4.x
-        from jax.experimental.shard_map import shard_map as _shard_map
-        shard_map = partial(_shard_map, check_rep=False)
+    shard_map = partial(jax.shard_map, check_vma=False)
 
     from repro.core import buffer as rb
     from repro.core import distributed as dist

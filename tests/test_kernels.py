@@ -9,31 +9,21 @@ from repro.core import buffer as rb
 from repro.kernels import ops, ref
 
 
+def _row0(outs):
+    """The first query's row of every output of a batched op."""
+    return tuple(o[0] for o in outs)
+
+
 @pytest.mark.parametrize("n", [256, 1000, 4096])
 @pytest.mark.parametrize("m_sub,k_codes", [(16, 16), (32, 16), (33, 16)])
 @pytest.mark.parametrize("dtype", [jnp.uint8, jnp.int32])
 def test_pq_adc(rng, n, m_sub, k_codes, dtype):
     codes = jnp.asarray(rng.integers(0, k_codes, (n, m_sub)), dtype)
     lut = jnp.asarray(rng.random((m_sub, k_codes)), jnp.float32)
-    got = ops.pq_adc(codes, lut)
+    got = ops.pq_adc_batch(codes, lut[None], backend="pallas")[0]
     want = ref.pq_adc(codes, lut)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.parametrize("n,d", [(256, 64), (300, 96), (1024, 128), (512, 100)])
-def test_rabitq_est(rng, n, d):
-    codes = jnp.asarray(rng.choice([-1, 1], (n, d)), jnp.int8)
-    norm_o = jnp.asarray(rng.random(n) * 5 + 0.5, jnp.float32)
-    f_o = jnp.asarray(rng.random(n) * 0.3 + 0.6, jnp.float32)
-    v = jnp.asarray(rng.standard_normal(d), jnp.float32)
-    v = v / jnp.linalg.norm(v)
-    norm_q = jnp.float32(3.3)
-    got = ops.rabitq_est(codes, norm_o, f_o, v, norm_q)
-    want = ref.rabitq_est(codes, norm_o, f_o, v, norm_q)
-    for g, w in zip(got, want):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                   rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.parametrize("n", [512, 2000, 8192])
@@ -43,8 +33,9 @@ def test_bucket_hist(rng, n, m):
     valid = jnp.asarray(rng.random(n) < 0.9)
     dists = jnp.where(valid, dists, jnp.inf)
     cb = rb.build_codebook(dists, k=min(n // 2, 1000), m=m)
-    got_b, got_h = ops.bucket_hist(dists, valid, cb.d_min, cb.delta,
-                                   cb.ew_map, m)
+    got_b, got_h = _row0(ops.bucket_hist_batch(
+        dists[None], valid[None], cb.d_min[None], cb.delta[None],
+        cb.ew_map[None], m, backend="pallas"))
     want_b, want_h = ref.bucket_hist(dists, valid, cb.d_min, cb.delta,
                                      cb.ew_map, m)
     np.testing.assert_array_equal(np.asarray(got_b), np.asarray(want_b))
@@ -67,8 +58,9 @@ def test_fused_scan(rng, n, d, m_sub):
     cb = rb.build_codebook(jnp.where(valid, est_ref, jnp.inf),
                            k=min(n // 2, 500), m=m)
     tau = jnp.int32(m // 3)
-    got = ops.fused_scan(codes, vectors, valid, lut, q, cb.d_min, cb.delta,
-                         cb.ew_map, m, tau)
+    got = _row0(ops.fused_scan_batch(
+        codes, vectors, valid[None], lut[None], q[None], cb.d_min[None],
+        cb.delta[None], cb.ew_map[None], m, tau[None], backend="pallas"))
     want = ref.fused_scan(codes, vectors, valid, lut, q, cb.d_min, cb.delta,
                           cb.ew_map, m, tau)
     names = ["est", "bucket", "hist", "early", "nmiss"]
@@ -91,7 +83,7 @@ def test_fused_scan(rng, n, d, m_sub):
 def test_l2_exact(rng, n, d):
     x = jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
     q = jnp.asarray(rng.standard_normal(d), jnp.float32)
-    got = ops.l2_exact(x, q)
+    got = ops.l2_exact_batch(x, q[None], backend="pallas")[0]
     want = ref.l2_exact(x, q)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
@@ -99,7 +91,7 @@ def test_l2_exact(rng, n, d):
 
 # ------------------------- batched kernels ---------------------------------
 
-@pytest.mark.parametrize("b", [1, 5, 8])
+@pytest.mark.parametrize("b", [1, 5, 8, 19])
 @pytest.mark.parametrize("n,m_sub", [(512, 16), (1000, 33)])
 def test_pq_adc_batch(rng, b, n, m_sub):
     k_codes = 16
@@ -110,12 +102,11 @@ def test_pq_adc_batch(rng, b, n, m_sub):
         got = ops.pq_adc_batch(codes, luts, backend=backend)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-5)
-    # rows agree with the single-query wrapper
+    # rows are bit-identical to a singleton batch of the same query
     got = ops.pq_adc_batch(codes, luts, backend="pallas")
-    for bi in range(min(b, 2)):
-        np.testing.assert_allclose(np.asarray(got[bi]),
-                                   np.asarray(ops.pq_adc(codes, luts[bi])),
-                                   rtol=1e-5, atol=1e-5)
+    for bi in (0, b - 1):
+        one = ops.pq_adc_batch(codes, luts[bi][None], backend="pallas")[0]
+        np.testing.assert_array_equal(np.asarray(got[bi]), np.asarray(one))
 
 
 def _batch_codebooks(rng, est_rows, k, m):
@@ -142,19 +133,23 @@ def test_bucket_hist_batch(rng, b, n):
             jnp.asarray(dists), jnp.asarray(valid), d_min, delta, ew, m)
         np.testing.assert_array_equal(np.asarray(got_b), np.asarray(want_b))
         np.testing.assert_array_equal(np.asarray(got_h), np.asarray(want_h))
-        # and each row agrees with the single-query kernel
-        for bi in range(b):
-            srow, shist = ops.bucket_hist(
-                jnp.asarray(dists[bi]), jnp.asarray(valid[bi]), d_min[bi],
-                delta[bi], ew[bi], m)
-            np.testing.assert_array_equal(np.asarray(got_b[bi]),
-                                          np.asarray(srow))
-            np.testing.assert_array_equal(np.asarray(got_h[bi]),
-                                          np.asarray(shist))
+    # and each row agrees with a singleton batch of the same query
+    got_b, got_h = ops.bucket_hist_batch(
+        jnp.asarray(dists), jnp.asarray(valid), d_min, delta, ew, m,
+        backend="pallas")
+    for bi in range(b):
+        srow, shist = _row0(ops.bucket_hist_batch(
+            jnp.asarray(dists[bi:bi + 1]), jnp.asarray(valid[bi:bi + 1]),
+            d_min[bi:bi + 1], delta[bi:bi + 1], ew[bi:bi + 1], m,
+            backend="pallas"))
+        np.testing.assert_array_equal(np.asarray(got_b[bi]), np.asarray(srow))
+        np.testing.assert_array_equal(np.asarray(got_h[bi]),
+                                      np.asarray(shist))
 
 
 @pytest.mark.parametrize("b,n,d,m_sub", [(4, 512, 64, 16), (8, 768, 96, 24),
-                                         (3, 512, 128, 32)])
+                                         (3, 512, 128, 32),
+                                         (13, 512, 64, 16)])
 def test_fused_scan_batch(rng, b, n, d, m_sub):
     k_codes, m = 16, 64
     codes = jnp.asarray(rng.integers(0, k_codes, (n, m_sub)), jnp.uint8)
@@ -180,21 +175,19 @@ def test_fused_scan_batch(rng, b, n, d, m_sub):
         else:
             np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                        rtol=1e-4, atol=1e-4)
-    # per-row agreement with the single-query fused kernel
-    for bi in range(min(b, 2)):
-        single = ops.fused_scan(codes, vectors, valid[bi], luts[bi], qs[bi],
-                                d_min[bi], delta[bi], ew[bi], m, tau[bi])
-        np.testing.assert_allclose(np.asarray(got[0][bi]),
-                                   np.asarray(single[0]), rtol=1e-4,
-                                   atol=1e-4)
-        np.testing.assert_array_equal(np.asarray(got[1][bi]),
-                                      np.asarray(single[1]))
-        np.testing.assert_array_equal(np.asarray(got[2][bi]),
-                                      np.asarray(single[2]))
-        assert int(got[4][bi]) == int(single[4])
+    # rows are bit-identical to a singleton batch of the same query
+    for bi in (0, b - 1):
+        sl = slice(bi, bi + 1)
+        single = _row0(ops.fused_scan_batch(
+            codes, vectors, valid[sl], luts[sl], qs[sl], d_min[sl],
+            delta[sl], ew[sl], m, tau[sl], backend="pallas"))
+        for name, g, one in zip(names, got, single):
+            np.testing.assert_array_equal(np.asarray(g[bi]), np.asarray(one),
+                                          err_msg=name)
 
 
-@pytest.mark.parametrize("b,n,d", [(4, 512, 64), (9, 999, 96), (1, 256, 128)])
+@pytest.mark.parametrize("b,n,d", [(4, 512, 64), (9, 999, 96), (1, 256, 128),
+                                   (24, 512, 128)])
 def test_l2_exact_batch(rng, b, n, d):
     x = jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
     qs = jnp.asarray(rng.standard_normal((b, d)), jnp.float32)
@@ -203,12 +196,11 @@ def test_l2_exact_batch(rng, b, n, d):
         got = ops.l2_exact_batch(x, qs, backend=backend)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-4, atol=2e-4)
-    # rows agree with the single-query kernel
+    # rows are bit-identical to a singleton batch of the same query
     got = ops.l2_exact_batch(x, qs, backend="pallas")
-    for bi in range(min(b, 2)):
-        np.testing.assert_allclose(np.asarray(got[bi]),
-                                   np.asarray(ops.l2_exact(x, qs[bi])),
-                                   rtol=2e-4, atol=2e-4)
+    for bi in (0, b - 1):
+        one = ops.l2_exact_batch(x, qs[bi][None], backend="pallas")[0]
+        np.testing.assert_array_equal(np.asarray(got[bi]), np.asarray(one))
 
 
 def test_fused_scan_matches_search_semantics(rng):
@@ -223,9 +215,10 @@ def test_fused_scan_matches_search_semantics(rng):
     lut = jnp.asarray(rng.random((m_sub, k_codes)) * 2, jnp.float32)
     est = jnp.sqrt(jnp.maximum(ref.pq_adc(codes, lut), 0.0))
     cb = rb.build_codebook(est, k=256, m=m)
-    _, bucket, hist, _, _ = ops.fused_scan(
-        codes, vectors, valid, lut, q, cb.d_min, cb.delta, cb.ew_map, m,
-        jnp.int32(m))
+    _, bucket, hist, _, _ = _row0(ops.fused_scan_batch(
+        codes, vectors, valid[None], lut[None], q[None], cb.d_min[None],
+        cb.delta[None], cb.ew_map[None], m, jnp.int32(m)[None],
+        backend="pallas"))
     core_hist = rb.histogram(rb.bucketize(cb, est), m, valid)
     np.testing.assert_array_equal(np.asarray(hist), np.asarray(core_hist))
     tau_k, _ = rb.threshold_bucket(jnp.asarray(hist), 256)

@@ -74,6 +74,14 @@ def net():
     ms.shutdown()
 
 
+def test_ready_frames_report_worker_devices(net):
+    """Every worker's READY frame names the devices its engine runs on."""
+    devices = net.ms.worker_devices
+    assert sorted(devices) == list(range(net.cfg.n_workers))
+    assert all(devs and all(isinstance(d, str) for d in devs)
+               for devs in devices.values()), devices
+
+
 def test_live_roundtrip_parity_and_cache(net):
     trace = _trace(40)
     with NetClient(net.ms.addr) as c:
@@ -228,6 +236,27 @@ def test_live_worker_death_detection_and_respawn(tmp_path, monkeypatch):
     finally:
         stop.set()
         th.join(timeout=10.0)
+        ms.shutdown()
+
+
+def test_worker_startup_failure_ends_the_wait(tmp_path):
+    """A worker that dies before READY (here: an engine spec it cannot
+    build) ends ``wait_workers`` at once instead of being respawned until
+    the timeout."""
+    class BadSpecMaster(MasterServer):
+        def _worker_spec(self, wid):
+            spec = super()._worker_spec(wid)
+            spec["engine"] = dict(spec["engine"], n=-1)
+            return spec
+
+    cfg = MasterConfig(n_workers=1, ceilings=k_ceilings(KS))
+    ms = BadSpecMaster(cfg, SPEC, run_dir=str(tmp_path))
+    ms.start()
+    try:
+        t0 = time.monotonic()
+        assert not ms.wait_workers(timeout=300.0)
+        assert time.monotonic() - t0 < 120.0
+    finally:
         ms.shutdown()
 
 
