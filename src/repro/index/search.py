@@ -24,6 +24,14 @@ engine-owned EMA of previous batches' bucket histograms) and the call returns
 threshold bucket instead of the static knobs (see the predictive section
 below and ``core.rerank.predict_tau``).
 
+The single-device batched searchers wrap their stages in ``jax.named_scope``
+(``bbc.route``, ``bbc.plan``, ``bbc.scan``, ``bbc.collect``,
+``bbc.rerank``, ``bbc.final``: routing, the codebook / gate plan, the
+stream scan, the collection, the exact re-rank, the final selection), so
+every op of the compiled program carries its stage in its HLO ``op_name``
+and a profile can charge device time to a stage.  A scope is metadata only: it costs nothing
+at run time.
+
 Method map (paper Table / Fig. 1):
   ivf_search(use_bbc=False)          -> IVF
   ivf_pq_search(use_bbc=False)       -> IVF+PQ          (unbounded, n_cand)
@@ -549,46 +557,59 @@ def ivf_search_batch(
     collection — sees them exactly like unprobed lanes.  The value is
     traced (not static): flipping tombstones never recompiles.
     """
-    probed, lane_valid, _ = _routing(index, layout, qs, n_probe)
-    if live is not None:
-        lane_valid = lane_valid & live[None, :]
-    stream_vecs = vectors[layout.order]                       # shared gather
-    dists = ops.l2_exact_batch(stream_vecs, qs, backend=backend)
-    dists = jnp.where(lane_valid, dists, INF)
-    n = jnp.sum(lane_valid, axis=1).astype(jnp.int32)
+    with jax.named_scope("bbc.route"):
+        probed, lane_valid, _ = _routing(index, layout, qs, n_probe)
+        if live is not None:
+            lane_valid = lane_valid & live[None, :]
+    with jax.named_scope("bbc.scan"):
+        stream_vecs = vectors[layout.order]                   # shared gather
+        dists = ops.l2_exact_batch(stream_vecs, qs, backend=backend)
+        dists = jnp.where(lane_valid, dists, INF)
     if pred_state is not None:
         if not use_bbc:
             raise ValueError("predictive search requires use_bbc=True")
         # distances are exact in-scan, so the pool target is k itself
         count = max(pred_count, k) if pred_count is not None else k
         st = min(4, n_probe)
-        cbs = _sample_codebooks(layout, probed, dists, st, index.cap, k, m)
-        bucket, hist = ops.bucket_hist_batch(
-            dists, lane_valid, cbs.d_min, cbs.delta, cbs.ew_map, m,
-            backend=backend)
-        tau_pred = rerank.predict_tau(pred_state, count)
-        budget = _pred_budget(count, layout.n_flat)
-        sel_d, sel_pos, sel_ok, _ = _predictive_select(
-            dists, bucket, hist, lane_valid, tau_pred, count, budget,
-            layout.order)
-        ids = jnp.where(sel_ok, layout.order[sel_pos], -1)
-        res = SearchResult(sel_d[:, :k], ids[:, :k], n, jnp.zeros_like(n))
-        return res, rerank.predictor_update(pred_state, hist)
-    if use_bbc and ops.resolve_backend(backend) == "pallas":
-        # Kernel path: O(m) histogram collection (bucket_hist kernel) + one
-        # (k + slack)-wide selection.
-        st = min(4, n_probe)
-        spos, sok = ivf_mod.tile_positions(layout, probed[:, :st], index.cap)
-        sample = jnp.where(sok, jnp.take_along_axis(dists, spos, axis=1), INF)
-        d, i = col.bbc_collect_batch(dists, layout.order, lane_valid, k, m=m,
-                                     sample=sample, sample_valid=sok,
-                                     backend=backend)
-    else:
-        # CPU fallback: XLA's flat top_k beats scatter-based compaction at
-        # these widths; the selected set is identical (bucketize is monotone
-        # in distance, so the bucket collection selects the exact top-k set).
-        d, i = col.topk_collect_batch(dists, layout.order, lane_valid, k)
-    return SearchResult(d, i, n, jnp.zeros_like(n))
+        with jax.named_scope("bbc.plan"):
+            cbs = _sample_codebooks(layout, probed, dists, st, index.cap, k,
+                                    m)
+            tau_pred = rerank.predict_tau(pred_state, count)
+        with jax.named_scope("bbc.scan"):
+            bucket, hist = ops.bucket_hist_batch(
+                dists, lane_valid, cbs.d_min, cbs.delta, cbs.ew_map, m,
+                backend=backend)
+        with jax.named_scope("bbc.collect"):
+            budget = _pred_budget(count, layout.n_flat)
+            sel_d, sel_pos, sel_ok, _ = _predictive_select(
+                dists, bucket, hist, lane_valid, tau_pred, count, budget,
+                layout.order)
+        with jax.named_scope("bbc.final"):
+            n = jnp.sum(lane_valid, axis=1).astype(jnp.int32)
+            ids = jnp.where(sel_ok, layout.order[sel_pos], -1)
+            res = SearchResult(sel_d[:, :k], ids[:, :k], n, jnp.zeros_like(n))
+            return res, rerank.predictor_update(pred_state, hist)
+    with jax.named_scope("bbc.collect"):
+        if use_bbc and ops.resolve_backend(backend) == "pallas":
+            # Kernel path: O(m) histogram collection (bucket_hist kernel) +
+            # one (k + slack)-wide selection.
+            st = min(4, n_probe)
+            spos, sok = ivf_mod.tile_positions(layout, probed[:, :st],
+                                               index.cap)
+            sample = jnp.where(sok, jnp.take_along_axis(dists, spos, axis=1),
+                               INF)
+            d, i = col.bbc_collect_batch(dists, layout.order, lane_valid, k,
+                                         m=m, sample=sample, sample_valid=sok,
+                                         backend=backend)
+        else:
+            # CPU fallback: XLA's flat top_k beats scatter-based compaction
+            # at these widths; the selected set is identical (bucketize is
+            # monotone in distance, so the bucket collection selects the
+            # exact top-k set).
+            d, i = col.topk_collect_batch(dists, layout.order, lane_valid, k)
+    with jax.named_scope("bbc.final"):
+        n = jnp.sum(lane_valid, axis=1).astype(jnp.int32)
+        return SearchResult(d, i, n, jnp.zeros_like(n))
 
 
 @functools.partial(
@@ -633,14 +654,17 @@ def ivf_pq_search_batch(
         fused = ops.on_tpu()
     ivf = index.ivf
     b = qs.shape[0]
-    probed, lane_valid, _ = _routing(ivf, layout, qs, n_probe)
-    if live is not None:
-        # tombstoned lanes (streaming-ingest deletes) behave exactly like
-        # unprobed lanes from here on: masked out of estimates, histograms,
-        # and the collection alike
-        lane_valid = lane_valid & live[None, :]
-    stream_codes = index.codes[layout.order]                  # shared gather
-    luts = jax.vmap(lambda q: pq_mod.adc_table(index.pq, q))(qs)
+    with jax.named_scope("bbc.route"):
+        probed, lane_valid, _ = _routing(ivf, layout, qs, n_probe)
+        if live is not None:
+            # tombstoned lanes (streaming-ingest deletes) behave exactly like
+            # unprobed lanes from here on: masked out of estimates,
+            # histograms, and the collection alike
+            lane_valid = lane_valid & live[None, :]
+    with jax.named_scope("bbc.scan"):
+        stream_codes = index.codes[layout.order]              # shared gather
+    with jax.named_scope("bbc.plan"):
+        luts = jax.vmap(lambda q: pq_mod.adc_table(index.pq, q))(qs)
 
     if pred_state is not None:
         if not use_bbc:
@@ -652,21 +676,26 @@ def ivf_pq_search_batch(
     dense_rerank = 4 * n_cand >= layout.n_flat
 
     if not use_bbc:
-        est2 = ops.pq_adc_batch(stream_codes, luts, backend=backend)
-        est = jnp.where(lane_valid, jnp.sqrt(jnp.maximum(est2, 0.0)), INF)
-        sel_est, sel_pos = jax.lax.top_k(-est, n_cand)
-        ci = jnp.where(jnp.isfinite(sel_est), layout.order[sel_pos], -1)
-        if dense_rerank:
-            stream_vecs = index.vectors[layout.order]
-            exact_all = ops.l2_exact_batch(stream_vecs, qs, backend=backend)
-            ex = jnp.take_along_axis(exact_all, sel_pos, axis=1)
-        else:
-            ex = _exact_dists_rows(index.vectors, ci, qs)
-        ex = jnp.where(ci >= 0, ex, INF)
-        neg, order = jax.lax.top_k(-ex, k)
-        counts = jnp.full((b,), n_cand, jnp.int32)
-        return SearchResult(-neg, jnp.take_along_axis(ci, order, axis=1),
-                            counts, counts)
+        with jax.named_scope("bbc.scan"):
+            est2 = ops.pq_adc_batch(stream_codes, luts, backend=backend)
+            est = jnp.where(lane_valid, jnp.sqrt(jnp.maximum(est2, 0.0)), INF)
+        with jax.named_scope("bbc.collect"):
+            sel_est, sel_pos = jax.lax.top_k(-est, n_cand)
+            ci = jnp.where(jnp.isfinite(sel_est), layout.order[sel_pos], -1)
+        with jax.named_scope("bbc.rerank"):
+            if dense_rerank:
+                stream_vecs = index.vectors[layout.order]
+                exact_all = ops.l2_exact_batch(stream_vecs, qs,
+                                               backend=backend)
+                ex = jnp.take_along_axis(exact_all, sel_pos, axis=1)
+            else:
+                ex = _exact_dists_rows(index.vectors, ci, qs)
+            ex = jnp.where(ci >= 0, ex, INF)
+        with jax.named_scope("bbc.final"):
+            neg, order = jax.lax.top_k(-ex, k)
+            counts = jnp.full((b,), n_cand, jnp.int32)
+            return SearchResult(-neg, jnp.take_along_axis(ci, order, axis=1),
+                                counts, counts)
 
     # ---- BBC path (Alg. 4, batched) ---------------------------------------
     n_flat = layout.n_flat
@@ -676,28 +705,32 @@ def ivf_pq_search_batch(
         # over the shared stream; selection via the histogram; second gather
         # pass only for selected-but-not-predicted stragglers.
         st = min(4, n_probe)
-        sample_est = _pq_sample_est(layout, probed, stream_codes, luts, st,
-                                    ivf.cap)
-        n_total = n_probe * ivf.cap
-        plans = jax.vmap(
-            lambda s: rerank.early_rerank_plan(
-                s, n_cand=n_cand, n_sample=s.shape[0], n_total=n_total, m=m)
-        )(sample_est)
+        with jax.named_scope("bbc.plan"):
+            sample_est = _pq_sample_est(layout, probed, stream_codes, luts,
+                                        st, ivf.cap)
+            n_total = n_probe * ivf.cap
+            plans = jax.vmap(
+                lambda s: rerank.early_rerank_plan(
+                    s, n_cand=n_cand, n_sample=s.shape[0], n_total=n_total,
+                    m=m)
+            )(sample_est)
 
-        stream_vecs = index.vectors[layout.order]
-        est, bucket, hist, early, nmiss = ops.fused_scan_batch(
-            stream_codes, stream_vecs, lane_valid, luts, qs,
-            plans.cb.d_min, plans.cb.delta, plans.cb.ew_map, m,
-            plans.tau_pred, backend=backend)
-        est = jnp.where(lane_valid, est, INF)
-        positions = jnp.arange(n_flat, dtype=jnp.int32)
-        _, sel_pos = col.collect_batch(est, positions, lane_valid, bucket,
-                                       hist, n_cand, m)
-        safe_pos = jnp.maximum(sel_pos, 0)
-        sel_ids = jnp.where(sel_pos >= 0, layout.order[safe_pos], -1)
-        e_at_sel = jnp.take_along_axis(early, safe_pos, axis=1)
-        have = jnp.isfinite(e_at_sel) & (sel_pos >= 0)
-        n_early = (jnp.sum(lane_valid, axis=1) - nmiss).astype(jnp.int32)
+        with jax.named_scope("bbc.scan"):
+            stream_vecs = index.vectors[layout.order]
+            est, bucket, hist, early, nmiss = ops.fused_scan_batch(
+                stream_codes, stream_vecs, lane_valid, luts, qs,
+                plans.cb.d_min, plans.cb.delta, plans.cb.ew_map, m,
+                plans.tau_pred, backend=backend)
+            est = jnp.where(lane_valid, est, INF)
+        with jax.named_scope("bbc.collect"):
+            positions = jnp.arange(n_flat, dtype=jnp.int32)
+            _, sel_pos = col.collect_batch(est, positions, lane_valid, bucket,
+                                           hist, n_cand, m)
+            safe_pos = jnp.maximum(sel_pos, 0)
+            sel_ids = jnp.where(sel_pos >= 0, layout.order[safe_pos], -1)
+            e_at_sel = jnp.take_along_axis(early, safe_pos, axis=1)
+            have = jnp.isfinite(e_at_sel) & (sel_pos >= 0)
+            n_early = (jnp.sum(lane_valid, axis=1) - nmiss).astype(jnp.int32)
     else:
         # CPU fallback: there is no VMEM-residency win to collect inline, so
         # skip the prediction machinery and select the exact top-n_cand by
@@ -705,35 +738,40 @@ def ivf_pq_search_batch(
         # yields — bucketize is monotone in the estimate; boundary ties
         # break by global id to match the sharded re-cut), then one exact
         # pass over the selection.
-        est2 = ops.pq_adc_batch(stream_codes, luts, backend=backend)
-        est = jnp.where(lane_valid, jnp.sqrt(jnp.maximum(est2, 0.0)), INF)
-        sel_est, sel_pos = _topk_est_id(est, layout.order, n_cand)
-        sel_ids = jnp.where(jnp.isfinite(-sel_est), layout.order[sel_pos], -1)
-        e_at_sel = jnp.full(sel_pos.shape, INF, est.dtype)
-        have = jnp.zeros(sel_pos.shape, bool)
-        n_early = jnp.zeros((b,), jnp.int32)
+        with jax.named_scope("bbc.scan"):
+            est2 = ops.pq_adc_batch(stream_codes, luts, backend=backend)
+            est = jnp.where(lane_valid, jnp.sqrt(jnp.maximum(est2, 0.0)), INF)
+        with jax.named_scope("bbc.collect"):
+            sel_est, sel_pos = _topk_est_id(est, layout.order, n_cand)
+            sel_ids = jnp.where(jnp.isfinite(-sel_est), layout.order[sel_pos],
+                                -1)
+            e_at_sel = jnp.full(sel_pos.shape, INF, est.dtype)
+            have = jnp.zeros(sel_pos.shape, bool)
+            n_early = jnp.zeros((b,), jnp.int32)
 
-    miss = ~have & (sel_ids >= 0)
-    if fused:
-        # stragglers only — keep the targeted per-row gather
-        miss_d = _exact_dists_rows(index.vectors,
-                                   jnp.where(miss, sel_ids, 0), qs)
-    elif dense_rerank:
-        # the whole selection misses (no inline pass on CPU): one shared
-        # matmul over the stream beats n_cand per-row gathers
-        stream_vecs = index.vectors[layout.order]
-        exact_all = ops.l2_exact_batch(stream_vecs, qs, backend=backend)
-        miss_d = jnp.take_along_axis(exact_all, jnp.maximum(sel_pos, 0),
-                                     axis=1)
-    else:
-        miss_d = _exact_dists_rows(index.vectors,
-                                   jnp.where(miss, sel_ids, 0), qs)
-    ex = jnp.where(have, e_at_sel, jnp.where(miss, miss_d, INF))
-    second = jnp.sum(miss, axis=1).astype(jnp.int32)
+    with jax.named_scope("bbc.rerank"):
+        miss = ~have & (sel_ids >= 0)
+        if fused:
+            # stragglers only — keep the targeted per-row gather
+            miss_d = _exact_dists_rows(index.vectors,
+                                       jnp.where(miss, sel_ids, 0), qs)
+        elif dense_rerank:
+            # the whole selection misses (no inline pass on CPU): one shared
+            # matmul over the stream beats n_cand per-row gathers
+            stream_vecs = index.vectors[layout.order]
+            exact_all = ops.l2_exact_batch(stream_vecs, qs, backend=backend)
+            miss_d = jnp.take_along_axis(exact_all, jnp.maximum(sel_pos, 0),
+                                         axis=1)
+        else:
+            miss_d = _exact_dists_rows(index.vectors,
+                                       jnp.where(miss, sel_ids, 0), qs)
+        ex = jnp.where(have, e_at_sel, jnp.where(miss, miss_d, INF))
+        second = jnp.sum(miss, axis=1).astype(jnp.int32)
 
-    neg, order = jax.lax.top_k(-ex, k)
-    return SearchResult(-neg, jnp.take_along_axis(sel_ids, order, axis=1),
-                        n_early + second, second)
+    with jax.named_scope("bbc.final"):
+        neg, order = jax.lax.top_k(-ex, k)
+        return SearchResult(-neg, jnp.take_along_axis(sel_ids, order, axis=1),
+                            n_early + second, second)
 
 
 def _ivf_pq_predictive_batch(index, qs, layout, probed, lane_valid,
@@ -754,70 +792,81 @@ def _ivf_pq_predictive_batch(index, qs, layout, probed, lane_valid,
     n_flat = layout.n_flat
     count = _resolve_pred_count(pred_count, k, n_cand)
     st = min(4, n_probe)
-    sample_est = _pq_sample_est(layout, probed, stream_codes, luts, st,
-                                ivf.cap)
-    k_cb = min(n_cand, sample_est.shape[1])
-    cbs = jax.vmap(lambda s: rb.build_codebook(s, k=k_cb, m=m))(sample_est)
-    tau_pred = rerank.predict_tau(pred_state, count)
+    with jax.named_scope("bbc.plan"):
+        sample_est = _pq_sample_est(layout, probed, stream_codes, luts, st,
+                                    ivf.cap)
+        k_cb = min(n_cand, sample_est.shape[1])
+        cbs = jax.vmap(lambda s: rb.build_codebook(s, k=k_cb, m=m))(
+            sample_est)
+        tau_pred = rerank.predict_tau(pred_state, count)
 
-    if fused:
-        stream_vecs = index.vectors[layout.order]
-        est, bucket, hist, early, nmiss = ops.fused_scan_batch(
-            stream_codes, stream_vecs, lane_valid, luts, qs,
-            cbs.d_min, cbs.delta, cbs.ew_map, m,
-            jnp.full((b,), tau_pred, jnp.int32), backend=backend)
-        est = jnp.where(lane_valid, est, INF)
-        n_early = (jnp.sum(lane_valid, axis=1) - nmiss).astype(jnp.int32)
-    else:
-        # CPU: no VMEM-residency win to collect inline — the whole pool goes
-        # through the (much smaller than n_cand) fallback gather instead.
-        est2 = ops.pq_adc_batch(stream_codes, luts, backend=backend)
-        est = jnp.where(lane_valid, jnp.sqrt(jnp.maximum(est2, 0.0)), INF)
-        bucket, hist = ops.bucket_hist_batch(
-            est, lane_valid, cbs.d_min, cbs.delta, cbs.ew_map, m,
-            backend=backend)
-        early = None
-        n_early = jnp.zeros((b,), jnp.int32)
+    with jax.named_scope("bbc.scan"):
+        if fused:
+            stream_vecs = index.vectors[layout.order]
+            est, bucket, hist, early, nmiss = ops.fused_scan_batch(
+                stream_codes, stream_vecs, lane_valid, luts, qs,
+                cbs.d_min, cbs.delta, cbs.ew_map, m,
+                jnp.full((b,), tau_pred, jnp.int32), backend=backend)
+            est = jnp.where(lane_valid, est, INF)
+            n_early = (jnp.sum(lane_valid, axis=1) - nmiss).astype(jnp.int32)
+        else:
+            # CPU: no VMEM-residency win to collect inline — the whole pool
+            # goes through the (much smaller than n_cand) fallback gather
+            # instead.
+            est2 = ops.pq_adc_batch(stream_codes, luts, backend=backend)
+            est = jnp.where(lane_valid, jnp.sqrt(jnp.maximum(est2, 0.0)), INF)
+            bucket, hist = ops.bucket_hist_batch(
+                est, lane_valid, cbs.d_min, cbs.delta, cbs.ew_map, m,
+                backend=backend)
+            early = None
+            n_early = jnp.zeros((b,), jnp.int32)
 
     # Survivors form an est-prefix (bucketize is monotone), so est-priority
     # truncation at a budget <= n_cand keeps the pool a SUBSET of the static
     # n_cand-by-estimate cut: the predictive result can only match or shrink
     # the static selection, never pull in ids the static path couldn't see.
-    budget = min(_pred_budget(count, n_flat), n_cand)
-    _, sel_pos, sel_ok, tau_true = _predictive_select(
-        est, bucket, hist, lane_valid, tau_pred, count, budget, layout.order)
-    sel_ids = jnp.where(sel_ok, layout.order[sel_pos], -1)
+    with jax.named_scope("bbc.collect"):
+        budget = min(_pred_budget(count, n_flat), n_cand)
+        _, sel_pos, sel_ok, tau_true = _predictive_select(
+            est, bucket, hist, lane_valid, tau_pred, count, budget,
+            layout.order)
+        sel_ids = jnp.where(sel_ok, layout.order[sel_pos], -1)
 
-    # Fallback pass (undershoot correctness): survivors not covered inline —
-    # the fallback-plan mask at the selected positions.  On the unfused path
-    # nothing was computed inline, so the whole selection is fallback work.
-    if early is not None:
-        e_at_sel = jnp.take_along_axis(early, sel_pos, axis=1)
-        fb = rerank.predicted_fallback_mask(
-            bucket, lane_valid, jnp.full((b,), tau_pred, jnp.int32), tau_true)
-        miss = jnp.take_along_axis(fb, sel_pos, axis=1) & sel_ok
-        have = sel_ok & ~miss
-    else:
-        e_at_sel = jnp.full(sel_pos.shape, INF, est.dtype)
-        have = jnp.zeros(sel_pos.shape, bool)
-        miss = sel_ok
-    if not fused and 4 * budget >= n_flat:
-        # pool is a large fraction of the stream (large-k regime): one shared
-        # matmul beats per-row gathers, as in the static dense_rerank path
-        exact_all = ops.l2_exact_batch(index.vectors[layout.order], qs,
-                                       backend=backend)
-        miss_d = jnp.take_along_axis(exact_all, jnp.maximum(sel_pos, 0),
-                                     axis=1)
-    else:
-        miss_d = _exact_dists_rows(index.vectors,
-                                   jnp.where(miss, sel_ids, 0), qs)
-    ex = jnp.where(have, e_at_sel, jnp.where(miss, miss_d, INF))
-    second = jnp.sum(miss, axis=1).astype(jnp.int32)
+        # Fallback pass (undershoot correctness): survivors not covered
+        # inline — the fallback-plan mask at the selected positions.  On the
+        # unfused path nothing was computed inline, so the whole selection is
+        # fallback work.
+        if early is not None:
+            e_at_sel = jnp.take_along_axis(early, sel_pos, axis=1)
+            fb = rerank.predicted_fallback_mask(
+                bucket, lane_valid, jnp.full((b,), tau_pred, jnp.int32),
+                tau_true)
+            miss = jnp.take_along_axis(fb, sel_pos, axis=1) & sel_ok
+            have = sel_ok & ~miss
+        else:
+            e_at_sel = jnp.full(sel_pos.shape, INF, est.dtype)
+            have = jnp.zeros(sel_pos.shape, bool)
+            miss = sel_ok
+    with jax.named_scope("bbc.rerank"):
+        if not fused and 4 * budget >= n_flat:
+            # pool is a large fraction of the stream (large-k regime): one
+            # shared matmul beats per-row gathers, as in the static
+            # dense_rerank path
+            exact_all = ops.l2_exact_batch(index.vectors[layout.order], qs,
+                                           backend=backend)
+            miss_d = jnp.take_along_axis(exact_all, jnp.maximum(sel_pos, 0),
+                                         axis=1)
+        else:
+            miss_d = _exact_dists_rows(index.vectors,
+                                       jnp.where(miss, sel_ids, 0), qs)
+        ex = jnp.where(have, e_at_sel, jnp.where(miss, miss_d, INF))
+        second = jnp.sum(miss, axis=1).astype(jnp.int32)
 
-    neg, order = jax.lax.top_k(-ex, k)
-    res = SearchResult(-neg, jnp.take_along_axis(sel_ids, order, axis=1),
-                       n_early + second, second)
-    return res, rerank.predictor_update(pred_state, hist)
+    with jax.named_scope("bbc.final"):
+        neg, order = jax.lax.top_k(-ex, k)
+        res = SearchResult(-neg, jnp.take_along_axis(sel_ids, order, axis=1),
+                           n_early + second, second)
+        return res, rerank.predictor_update(pred_state, hist)
 
 
 def _rabitq_batch_bounds(index: RabitqIndex, stream: RabitqStream,
@@ -1003,15 +1052,18 @@ def ivf_rabitq_search_batch(
     if fused is None:
         fused = True
     if stream is None:
-        stream = rabitq_stream(index, layout)
+        with jax.named_scope("bbc.scan"):
+            stream = rabitq_stream(index, layout)
     ivf = index.ivf
     b = qs.shape[0]
     cap = ivf.cap
-    probed, lane_valid, d2 = _routing(ivf, layout, qs, n_probe)
-    if live is not None:
-        # tombstones ride the lane-mask mechanism: every downstream
-        # consumer (bounds, band, histogram, collection) already honors it
-        lane_valid = lane_valid & live[None, :]
+    with jax.named_scope("bbc.route"):
+        probed, lane_valid, d2 = _routing(ivf, layout, qs, n_probe)
+        if live is not None:
+            # tombstones ride the lane-mask mechanism: every downstream
+            # consumer (bounds, band, histogram, collection) already honors
+            # it
+            lane_valid = lane_valid & live[None, :]
     n_flat = layout.n_flat
     stream_ids = layout.order
 
@@ -1020,17 +1072,19 @@ def ivf_rabitq_search_batch(
             index, stream, qs, layout, probed, lane_valid, d2, k, n_probe,
             m, eps0, backend, pred_state, pred_count)
 
-    est, lb, ub = _rabitq_batch_bounds(index, stream, qs, lane_valid, eps0,
-                                       d2=d2)
+    with jax.named_scope("bbc.scan"):
+        est, lb, ub = _rabitq_batch_bounds(index, stream, qs, lane_valid,
+                                           eps0, d2=d2)
 
     if not use_bbc:
         # ---- baseline: per-cluster threshold re-ranking, vmapped ----------
-        tpos, tok = ivf_mod.tile_positions(layout, probed, cap)
-        lb_t = jnp.where(tok, jnp.take_along_axis(lb, tpos, axis=1), INF)
-        ids_t = jnp.where(tok, stream_ids[tpos], -1)
-        lb_t = lb_t.reshape(b, n_probe, cap)
-        ids_t = ids_t.reshape(b, n_probe, cap)
-        ok_t = tok.reshape(b, n_probe, cap)
+        with jax.named_scope("bbc.collect"):
+            tpos, tok = ivf_mod.tile_positions(layout, probed, cap)
+            lb_t = jnp.where(tok, jnp.take_along_axis(lb, tpos, axis=1), INF)
+            ids_t = jnp.where(tok, stream_ids[tpos], -1)
+            lb_t = lb_t.reshape(b, n_probe, cap)
+            ids_t = ids_t.reshape(b, n_probe, cap)
+            ok_t = tok.reshape(b, n_probe, cap)
         budget = min(cap, _rerank_budget(k, cap))
 
         def one_query(args):
@@ -1058,9 +1112,11 @@ def ivf_rabitq_search_batch(
             order = jnp.argsort(pd)
             return pd[order], pi[order], n_rr
 
-        pd, pi, n_rr = jax.lax.map(one_query, (lb_t, ids_t, ok_t, qs))
-        return SearchResult(pd, pi, n_rr.astype(jnp.int32),
-                            n_rr.astype(jnp.int32))
+        with jax.named_scope("bbc.rerank"):
+            pd, pi, n_rr = jax.lax.map(one_query, (lb_t, ids_t, ok_t, qs))
+        with jax.named_scope("bbc.final"):
+            return SearchResult(pd, pi, n_rr.astype(jnp.int32),
+                                n_rr.astype(jnp.int32))
 
     # ---- two-phase BBC reference path (Alg. 3, batched greedy) -------------
     # Plan from the full-stream ub top-k (order-statistic thresholds), then
@@ -1070,28 +1126,34 @@ def ivf_rabitq_search_batch(
     # contender (``fused=False``): bench_rabitq_fused measures the fused
     # path against it, and its predictive counters are the MODELED
     # second-pass volume the fused path's measured counts must reproduce.
-    plan = rerank.greedy_rerank_plan_batch(lb, ub, k, lane_valid, m=m)
-    exact_all = ops.l2_exact_batch(stream.vectors, qs, backend=backend)
-    exact_flat = jnp.where(plan.rerank_mask, exact_all, INF)
+    with jax.named_scope("bbc.plan"):
+        plan = rerank.greedy_rerank_plan_batch(lb, ub, k, lane_valid, m=m)
+    with jax.named_scope("bbc.collect"):
+        n_evals = jnp.sum(plan.rerank_mask, axis=1).astype(jnp.int32)
+    with jax.named_scope("bbc.rerank"):
+        exact_all = ops.l2_exact_batch(stream.vectors, qs, backend=backend)
+        exact_flat = jnp.where(plan.rerank_mask, exact_all, INF)
 
-    res = jax.vmap(
-        lambda p, ef, lbv, e: rerank.greedy_rerank_finalize(
-            p, ef, lbv, stream_ids, k, est=e)
-    )(plan, exact_flat, jnp.where(lane_valid, lb, INF), est)
-    n_evals = jnp.sum(plan.rerank_mask, axis=1).astype(jnp.int32)
-    if pred_state is not None:
-        # inline coverage: band members predicted by the cross-batch tau; the
-        # fallback (second-pass gather) shrinks to the unpredicted remainder
-        count = max(pred_count, k) if pred_count is not None else k
-        tau_pred = rerank.predict_tau(pred_state, count)
-        covered = plan.rerank_mask & (plan.a_lb <= tau_pred)
-        n_second = jnp.sum(plan.rerank_mask & ~covered,
-                           axis=1).astype(jnp.int32)
-        hist_ub = jax.vmap(rb.histogram, in_axes=(0, None, 0))(
-            plan.a_ub, m, lane_valid)
-        res_p = SearchResult(res.topk_dists, res.topk_ids, n_evals, n_second)
-        return res_p, rerank.predictor_update(pred_state, hist_ub)
-    return SearchResult(res.topk_dists, res.topk_ids, n_evals, n_evals)
+    with jax.named_scope("bbc.final"):
+        res = jax.vmap(
+            lambda p, ef, lbv, e: rerank.greedy_rerank_finalize(
+                p, ef, lbv, stream_ids, k, est=e)
+        )(plan, exact_flat, jnp.where(lane_valid, lb, INF), est)
+        if pred_state is not None:
+            # inline coverage: band members predicted by the cross-batch tau;
+            # the fallback (second-pass gather) shrinks to the unpredicted
+            # remainder
+            count = max(pred_count, k) if pred_count is not None else k
+            tau_pred = rerank.predict_tau(pred_state, count)
+            covered = plan.rerank_mask & (plan.a_lb <= tau_pred)
+            n_second = jnp.sum(plan.rerank_mask & ~covered,
+                               axis=1).astype(jnp.int32)
+            hist_ub = jax.vmap(rb.histogram, in_axes=(0, None, 0))(
+                plan.a_ub, m, lane_valid)
+            res_p = SearchResult(res.topk_dists, res.topk_ids, n_evals,
+                                 n_second)
+            return res_p, rerank.predictor_update(pred_state, hist_ub)
+        return SearchResult(res.topk_dists, res.topk_ids, n_evals, n_evals)
 
 
 def _ivf_rabitq_fused_batch(index, stream, qs, layout, probed, lane_valid,
@@ -1116,136 +1178,159 @@ def _ivf_rabitq_fused_batch(index, stream, qs, layout, probed, lane_valid,
 
     est = lb = ub = None
     if kernel:
-        sample_ub, sok = _rabitq_sample_ub(
-            stream.codes, stream.norm_o, stream.f_o, stream.cl,
-            ivf.centroids, index.rq.rot, layout, probed, qs, d2, st,
-            ivf.cap, eps0)
+        with jax.named_scope("bbc.plan"):
+            sample_ub, sok = _rabitq_sample_ub(
+                stream.codes, stream.norm_o, stream.f_o, stream.cl,
+                ivf.centroids, index.rq.rot, layout, probed, qs, d2, st,
+                ivf.cap, eps0)
     else:
-        est, lb, ub = _rabitq_batch_bounds(index, stream, qs, lane_valid,
-                                           eps0, d2=d2)
-        spos, sok = ivf_mod.tile_positions(layout, probed[:, :st], ivf.cap)
-        sample_ub = jnp.where(sok, jnp.take_along_axis(ub, spos, axis=1),
-                              INF)
-    # the kernel takes its gate before the scan and cannot refresh it from
-    # the scan's own histogram (the composed form below does), so its static
-    # gate is the unscaled sample order statistic, which covers the band
-    cbs, tau_static = _rabitq_sample_plan(sample_ub, k, count, st, n_probe,
-                                          m, scale_rank=not kernel)
-    if pred_state is not None:
-        # the EMA gate, exactly as it gates the PQ pool: -1 while cold
-        # (nothing certified inline — the first batch behaves like the
-        # two-phase path), the predicted bucket once warm.  The EMA tracks
-        # the strided-subsample ub histogram, so the query count scales by
-        # the stride.
-        count_s = max(1, -(-count // _PRED_HIST_STRIDE))
-        # margin biased up: an overshooting gate certifies a few extra
-        # lanes (free — their exact distances ride the resident tile), an
-        # undershooting one pays real second-pass gathers
-        tau_inline = jnp.full(
-            (b,), rerank.predict_tau(pred_state, count_s,
-                                     margin=_PRED_GATE_MARGIN),
-            jnp.int32)
-    else:
-        tau_inline = tau_static
+        with jax.named_scope("bbc.scan"):
+            est, lb, ub = _rabitq_batch_bounds(index, stream, qs, lane_valid,
+                                               eps0, d2=d2)
+        with jax.named_scope("bbc.plan"):
+            spos, sok = ivf_mod.tile_positions(layout, probed[:, :st],
+                                               ivf.cap)
+            sample_ub = jnp.where(sok, jnp.take_along_axis(ub, spos, axis=1),
+                                  INF)
+    with jax.named_scope("bbc.plan"):
+        # the kernel takes its gate before the scan and cannot refresh it
+        # from the scan's own histogram (the composed form below does), so
+        # its static gate is the unscaled sample order statistic, which
+        # covers the band
+        cbs, tau_static = _rabitq_sample_plan(sample_ub, k, count, st,
+                                              n_probe, m,
+                                              scale_rank=not kernel)
+        if pred_state is not None:
+            # the EMA gate, exactly as it gates the PQ pool: -1 while cold
+            # (nothing certified inline — the first batch behaves like the
+            # two-phase path), the predicted bucket once warm.  The EMA
+            # tracks the strided-subsample ub histogram, so the query count
+            # scales by the stride.
+            count_s = max(1, -(-count // _PRED_HIST_STRIDE))
+            # margin biased up: an overshooting gate certifies a few extra
+            # lanes (free — their exact distances ride the resident tile),
+            # an undershooting one pays real second-pass gathers
+            tau_inline = jnp.full(
+                (b,), rerank.predict_tau(pred_state, count_s,
+                                         margin=_PRED_GATE_MARGIN),
+                jnp.int32)
+        else:
+            tau_inline = tau_static
 
-    if kernel:
-        # the fused kernel: codes + vectors co-tiled through VMEM, exact
-        # distances of certified lanes computed while the tile is resident
-        (est, lb, ub, bucket_lb, bucket_ub, _hist_lb, hist_ub, exact_c,
-         certified, _nmiss) = ops.fused_rabitq_scan_batch(
-            stream.codes, stream.vectors, stream.norm_o, stream.f_o,
-            stream.cl, ivf.centroids, rq.rot, qs, d2, lane_valid,
-            cbs.d_min, cbs.delta, cbs.ew_map, m, tau_inline, eps0=eps0,
-            backend=backend)
-        tau_ub = jax.vmap(rb.threshold_bucket, in_axes=(0, None))(
-            hist_ub, k)[0]
-        tau_lb = jax.vmap(rb.threshold_bucket, in_axes=(0, None))(
-            _hist_lb, k)[0]
-    else:
-        # composed CPU form of the same math: the scatter histograms the
-        # kernel accumulates for free are replaced by bisected threshold
-        # buckets (identical values), and the certified mask is applied to
-        # one shared exact matmul — no gather/fusion axis exists on CPU,
-        # so the restructured planning IS the speedup
-        bucket_lb = jax.vmap(rb.bucketize)(cbs, lb)
-        bucket_ub = jax.vmap(rb.bucketize)(cbs, ub)
-        taus = _tau_bucket_search(
-            jnp.concatenate([bucket_ub, bucket_lb], axis=0),
-            jnp.concatenate([lane_valid, lane_valid], axis=0), k, m)
-        tau_ub, tau_lb = taus[:b], taus[b:]
-        if pred_state is None:
-            # the stream-parallel CPU form has the full scan before the
-            # re-rank leg, so the static gate refreshes to the true band
-            # threshold (Alg. 4 line 14 at full progress — the same
-            # refresh the single-query PQ path documents); the predictive
-            # gate stays exactly tau_pred so the measured straggler count
-            # is the EMA's miss, comparable with the modeled volume
-            tau_inline = jnp.maximum(tau_inline, tau_ub)
-        certified = lane_valid & (bucket_lb <= tau_inline[:, None])
+    with jax.named_scope("bbc.scan"):
+        if kernel:
+            # the fused kernel: codes + vectors co-tiled through VMEM, exact
+            # distances of certified lanes computed while the tile is
+            # resident
+            (est, lb, ub, bucket_lb, bucket_ub, _hist_lb, hist_ub, exact_c,
+             certified, _nmiss) = ops.fused_rabitq_scan_batch(
+                stream.codes, stream.vectors, stream.norm_o, stream.f_o,
+                stream.cl, ivf.centroids, rq.rot, qs, d2, lane_valid,
+                cbs.d_min, cbs.delta, cbs.ew_map, m, tau_inline, eps0=eps0,
+                backend=backend)
+            tau_ub = jax.vmap(rb.threshold_bucket, in_axes=(0, None))(
+                hist_ub, k)[0]
+            tau_lb = jax.vmap(rb.threshold_bucket, in_axes=(0, None))(
+                _hist_lb, k)[0]
+        else:
+            # composed CPU form of the same math: the scatter histograms the
+            # kernel accumulates for free are replaced by bisected threshold
+            # buckets (identical values), and the certified mask is applied
+            # to one shared exact matmul — no gather/fusion axis exists on
+            # CPU, so the restructured planning IS the speedup
+            bucket_lb = jax.vmap(rb.bucketize)(cbs, lb)
+            bucket_ub = jax.vmap(rb.bucketize)(cbs, ub)
+            taus = _tau_bucket_search(
+                jnp.concatenate([bucket_ub, bucket_lb], axis=0),
+                jnp.concatenate([lane_valid, lane_valid], axis=0), k, m)
+            tau_ub, tau_lb = taus[:b], taus[b:]
+            if pred_state is None:
+                # the stream-parallel CPU form has the full scan before the
+                # re-rank leg, so the static gate refreshes to the true band
+                # threshold (Alg. 4 line 14 at full progress — the same
+                # refresh the single-query PQ path documents); the
+                # predictive gate stays exactly tau_pred so the measured
+                # straggler count is the EMA's miss, comparable with the
+                # modeled volume
+                tau_inline = jnp.maximum(tau_inline, tau_ub)
+            certified = lane_valid & (bucket_lb <= tau_inline[:, None])
 
-    certain_in = lane_valid & (bucket_ub < tau_lb[:, None])
-    band = lane_valid & (bucket_lb <= tau_ub[:, None]) & ~certain_in
-    straggler = band & ~certified
-    n_second = jnp.sum(straggler, axis=1).astype(jnp.int32)
-    n_evals = jnp.sum(band, axis=1).astype(jnp.int32)
+    with jax.named_scope("bbc.collect"):
+        certain_in = lane_valid & (bucket_ub < tau_lb[:, None])
+        band = lane_valid & (bucket_lb <= tau_ub[:, None]) & ~certain_in
+        straggler = band & ~certified
+        n_second = jnp.sum(straggler, axis=1).astype(jnp.int32)
+        n_evals = jnp.sum(band, axis=1).astype(jnp.int32)
+        if kernel:
+            # straggler-only second gather (the measured residue of Table
+            # 2): lb-priority compaction into a static budget, per-row
+            # exact, with a dense fallback should the gate miss more than
+            # the budget (a cold/undershooting predictor) — correctness
+            # never rides on it
+            budget = int(min(n_flat, ((max(2 * k, 2048) + 127) // 128) * 128))
+            key_lb = jnp.where(straggler, lb, INF)
+            neg, pos = jax.lax.top_k(-key_lb, budget)
+            okp = jnp.isfinite(-neg)
+            sids = jnp.where(okp, layout.order[pos], -1)
 
-    if kernel:
-        # straggler-only second gather (the measured residue of Table 2):
-        # lb-priority compaction into a static budget, per-row exact, with
-        # a dense fallback should the gate miss more than the budget (a
-        # cold/undershooting predictor) — correctness never rides on it
-        budget = int(min(n_flat, ((max(2 * k, 2048) + 127) // 128) * 128))
-        key_lb = jnp.where(straggler, lb, INF)
-        neg, pos = jax.lax.top_k(-key_lb, budget)
-        okp = jnp.isfinite(-neg)
-        sids = jnp.where(okp, layout.order[pos], -1)
-        sd = _exact_dists_rows(index.vectors, jnp.where(okp, sids, 0), qs)
-        filled = jnp.full((b, n_flat + 1), INF, sd.dtype)
-        filled = jax.vmap(
-            lambda f, p, v, o: f.at[jnp.where(o, p, n_flat)].set(v))(
-                filled, pos, sd, okp)[:, :n_flat]
-        exact_band = jnp.where(certified, exact_c, filled)
-        # only the overflowing queries take the dense values: a query's
-        # result must not depend on which other queries share its batch
-        # (the dense and gathered exact legs round differently on TPU)
-        overflow = n_second > budget                          # (B,)
+    with jax.named_scope("bbc.rerank"):
+        if kernel:
+            sd = _exact_dists_rows(index.vectors, jnp.where(okp, sids, 0), qs)
+            # one flat scatter over the batch, each row with a spare slot
+            # for the empty picks: the TPU compiler rewrites a batched
+            # (vmapped) scatter into this form itself, and drops its
+            # op_name, and so its stage, on the way
+            row = (n_flat + 1) * jnp.arange(b, dtype=pos.dtype)[:, None]
+            flat = (jnp.where(okp, pos, n_flat) + row).reshape(-1)
+            filled = jnp.full((b * (n_flat + 1),), INF, sd.dtype).at[
+                flat].set(sd.reshape(-1))
+            filled = filled.reshape(b, n_flat + 1)[:, :n_flat]
+            exact_band = jnp.where(certified, exact_c, filled)
+            # only the overflowing queries take the dense values: a query's
+            # result must not depend on which other queries share its batch
+            # (the dense and gathered exact legs round differently on TPU)
+            overflow = n_second > budget                      # (B,)
 
-        def dense(_):
-            allx = ops.l2_exact_batch(stream.vectors, qs, backend=backend)
-            return jnp.where(overflow[:, None],
-                             jnp.where(certified, exact_c, allx), exact_band)
+            def dense(_):
+                allx = ops.l2_exact_batch(stream.vectors, qs, backend=backend)
+                return jnp.where(overflow[:, None],
+                                 jnp.where(certified, exact_c, allx),
+                                 exact_band)
 
-        exact_band = jax.lax.cond(jnp.any(overflow), dense,
-                                  lambda _: exact_band, None)
-        exact_band = jnp.where(band, exact_band, INF)
-    else:
-        # one shared matmul serves the inline AND straggler legs (single
-        # float source: cold/warm/static variants stay bitwise identical);
-        # the counter is still the straggler-lane count of the executed
-        # certified gate — on TPU those lanes are the literal second gather
-        exact_all = ops.l2_exact_batch(stream.vectors, qs, backend=backend)
-        exact_band = jnp.where(band, exact_all, INF)
+            exact_band = jax.lax.cond(jnp.any(overflow), dense,
+                                      lambda _: exact_band, None)
+            exact_band = jnp.where(band, exact_band, INF)
+        else:
+            # one shared matmul serves the inline AND straggler legs (single
+            # float source: cold/warm/static variants stay bitwise
+            # identical); the counter is still the straggler-lane count of
+            # the executed certified gate — on TPU those lanes are the
+            # literal second gather
+            exact_all = ops.l2_exact_batch(stream.vectors, qs,
+                                           backend=backend)
+            exact_band = jnp.where(band, exact_all, INF)
 
-    plan = rerank.GreedyRerankPlan(
-        rerank_mask=band, certain_in=certain_in,
-        certain_out=lane_valid & ~band & ~certain_in,
-        tau_ub=tau_ub, tau_lb=tau_lb, a_lb=bucket_lb, a_ub=bucket_ub)
-    res = jax.vmap(
-        lambda p, ef, lbv, e: rerank.greedy_rerank_finalize(
-            p, ef, lbv, layout.order, k, est=e)
-    )(plan, exact_band, lb, est)
-    out = SearchResult(res.topk_dists, res.topk_ids, n_evals, n_second)
-    if pred_state is not None:
-        # EMA over the strided-subsample ub histogram: unbiased for the
-        # full probed set (see _PRED_HIST_STRIDE) at 1/stride of the
-        # scatter cost; bucket indices stay comparable batch-to-batch
-        # because the codebooks are equal-depth over samples of the same
-        # distribution
-        hist_s = jax.vmap(rb.histogram, in_axes=(0, None, 0))(
-            bucket_ub[:, ::_PRED_HIST_STRIDE], m,
-            lane_valid[:, ::_PRED_HIST_STRIDE])
-        return out, rerank.predictor_update(pred_state, hist_s)
-    return out
+    with jax.named_scope("bbc.final"):
+        plan = rerank.GreedyRerankPlan(
+            rerank_mask=band, certain_in=certain_in,
+            certain_out=lane_valid & ~band & ~certain_in,
+            tau_ub=tau_ub, tau_lb=tau_lb, a_lb=bucket_lb, a_ub=bucket_ub)
+        res = jax.vmap(
+            lambda p, ef, lbv, e: rerank.greedy_rerank_finalize(
+                p, ef, lbv, layout.order, k, est=e)
+        )(plan, exact_band, lb, est)
+        out = SearchResult(res.topk_dists, res.topk_ids, n_evals, n_second)
+        if pred_state is not None:
+            # EMA over the strided-subsample ub histogram: unbiased for the
+            # full probed set (see _PRED_HIST_STRIDE) at 1/stride of the
+            # scatter cost; bucket indices stay comparable batch-to-batch
+            # because the codebooks are equal-depth over samples of the same
+            # distribution
+            hist_s = jax.vmap(rb.histogram, in_axes=(0, None, 0))(
+                bucket_ub[:, ::_PRED_HIST_STRIDE], m,
+                lane_valid[:, ::_PRED_HIST_STRIDE])
+            return out, rerank.predictor_update(pred_state, hist_s)
+        return out
 
 
 # --------------------------------------------------------------------------

@@ -20,6 +20,16 @@ from the batched band evaluation and can legitimately differ near the k-th
 boundary, which is why the contract is stated against the batched entry
 point.)  Shed requests return nothing (``ids is None``): absent, never
 incorrect.
+
+Profiler spans (``jax.profiler.TraceAnnotation``; free unless a profile is
+being recorded) mark each batch's host steps on the profiler's clock, beside
+the device ops they wait on: ``serving.assemble`` (padding the batch),
+``serving.dispatch`` (the host-to-device copy and the enqueue),
+``serving.wait`` (blocking on the result) and ``serving.finish`` (turning
+the result into outcomes), which holds ``serving.fetch`` (the device-to-host
+copies) and ``serving.trim`` (the per-request trims).  Each carries the
+``batch_id`` the Server gives its batches and the bucket's ``k``; dispatch
+also carries ``n_real``, the batch's real requests.
 """
 from __future__ import annotations
 
@@ -153,19 +163,38 @@ class Server:
             allow_degrade=allow_degrade, slack_margin=slack_margin) \
             if admission else None
         self.service_time_fn = service_time_fn
+        # batches dispatched so far: the batch_id of the next one, which the
+        # profiler spans of one batch share
+        self._batches = 0
+
+    def _span(self, name: str, batch_id: int, bucket: ShapeBucket,
+              **stats) -> jax.profiler.TraceAnnotation:
+        return jax.profiler.TraceAnnotation(name, batch_id=batch_id,
+                                            bucket_k=bucket.k, **stats)
+
+    def _assemble(self, bucket: ShapeBucket,
+                  requests: Sequence[Request]) -> Batch:
+        """``batcher.assemble`` of the next batch to be dispatched."""
+        with self._span("serving.assemble", self._batches, bucket):
+            return assemble(bucket, requests)
 
     # -- engine execution ---------------------------------------------------
 
     def _serve(self, batch: Batch,
                overlap_fn: Callable[[], None] | None = None):
+        bid = self._batches
+        self._batches += 1
         t0 = time.perf_counter()
-        res = self.state.run(batch)
+        with self._span("serving.dispatch", bid, batch.bucket,
+                        n_real=batch.n_real):
+            res = self.state.run(batch)
         if overlap_fn is not None:
             # jax dispatch is async: the device is already executing this
             # batch; spend its service window on host work (next batch's
             # assembly) instead of blocking idle
             overlap_fn()
-        jax.block_until_ready((res.dists, res.ids))
+        with self._span("serving.wait", bid, batch.bucket):
+            jax.block_until_ready((res.dists, res.ids))
         dt = time.perf_counter() - t0
         if self.service_time_fn is not None:
             dt = self.service_time_fn(batch.bucket)
@@ -187,7 +216,7 @@ class Server:
                     if bucket_of(min(r.k, self.batcher.ceilings[-1]),
                                  r.n_probe, self.batcher.ceilings,
                                  self.batcher.batch) == bucket]
-            dt, _ = self._serve(assemble(bucket, reqs[:bucket.batch]))
+            dt, _ = self._serve(self._assemble(bucket, reqs[:bucket.batch]))
             self.service.observe(bucket, dt)
         return self
 
@@ -214,15 +243,20 @@ class Server:
 
     def _finish(self, batch: Batch, res, t_done: float,
                 outcomes: dict[int, Outcome]) -> None:
-        ids = np.asarray(res.ids)
-        dists = np.asarray(res.dists)
-        for j, req in enumerate(batch.requests):
-            status = DEGRADED if req.k_requested is not None else OK
-            d_j, i_j = trim_topk(dists[j], ids[j], req.k)
-            outcomes[req.rid] = Outcome(
-                request=req, status=status, bucket=batch.bucket,
-                ids=i_j.copy(), dists=d_j.copy(),
-                t_done=t_done, k_effective=req.k)
+        """Outcomes of the batch dispatched last."""
+        bid = self._batches - 1
+        with self._span("serving.finish", bid, batch.bucket):
+            with self._span("serving.fetch", bid, batch.bucket):
+                ids = np.asarray(res.ids)
+                dists = np.asarray(res.dists)
+            with self._span("serving.trim", bid, batch.bucket):
+                for j, req in enumerate(batch.requests):
+                    status = DEGRADED if req.k_requested is not None else OK
+                    d_j, i_j = trim_topk(dists[j], ids[j], req.k)
+                    outcomes[req.rid] = Outcome(
+                        request=req, status=status, bucket=batch.bucket,
+                        ids=i_j.copy(), dists=d_j.copy(),
+                        t_done=t_done, k_effective=req.k)
 
     def run_trace(self, trace: Sequence[Request],
                   warmup: bool = True) -> list[Outcome]:
@@ -246,7 +280,7 @@ class Server:
                 # batch j occupies the device (overlap on), or right after
                 # it completes (overlap off); either way exactly one
                 # assembled batch is in flight at a time
-                slot: list[Batch | None] = [assemble(*ready[0])]
+                slot: list[Batch | None] = [self._assemble(*ready[0])]
                 for j in range(len(ready)):
                     batch = slot[0]
                     t0 = t
@@ -259,7 +293,7 @@ class Server:
                                   for b2, _ in ready[j + 1:])
 
                     def _prep_next():
-                        slot[0] = assemble(*ready[j + 1]) \
+                        slot[0] = self._assemble(*ready[j + 1]) \
                             if j + 1 < len(ready) else None
 
                     dt, res = self._serve(

@@ -15,6 +15,7 @@ process may load the TPU library at a time, and only the test worker that
 runs this file should.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -162,3 +163,133 @@ def test_spec_compact_batch(one_chip, b):
              lambda bk, v, tau: sc.spec_compact_batch_pallas(
                  bk, v, tau, BUDGET, tile=TILE, interpret=False),
              ((b, N_ROWS), I32), ((b, N_ROWS), BOOL), ((b,), I32))
+
+
+# --------------------------------------------------------------------------
+# whole searchers: stage scopes survive the TPU compiler
+# --------------------------------------------------------------------------
+
+STAGES = {"bbc.route", "bbc.plan", "bbc.scan", "bbc.collect", "bbc.rerank",
+          "bbc.final"}
+SEARCH_OPS = ("fusion", "sort", "gather", "scatter", "custom-call")
+# compiler-made custom calls (buffer allocation, a relayout): no source op
+COMPILER_CALLS = ("AllocateBuffer", "ConcatBitcast")
+KERNELS = ("fused_scan_batch.", "fused_rabitq_scan_batch.", "l2_exact_batch.")
+_INST = re.compile(r"^\s*(?:ROOT )?%(\S+) = .*? ([a-z][a-z0-9-]*)\(")
+_CALLS = re.compile(r"calls=%([\w.\-]+)")
+
+
+def _computations(hlo: str) -> dict[str, list[str]]:
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) ", line)
+        if head and line.rstrip().endswith("{"):
+            name = head.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None and " = " in line:
+            comps[name].append(line)
+    return comps
+
+
+def _source_names(line: str, comps: dict) -> list[str]:
+    """The op_name of an instruction; for one the compiler made without
+    one (a fusion rooted in a bitcast, say), those of the ops inside it."""
+    own = re.search(r'op_name="([^"]*)"', line)
+    if own:
+        return [own.group(1)]
+    out, stack = [], _CALLS.findall(line)
+    while stack:
+        for inner in comps.get(stack.pop(), []):
+            name = re.search(r'op_name="([^"]*)"', inner)
+            out += [name.group(1)] if name else []
+            stack += _CALLS.findall(inner)
+    return out
+
+
+def _unstaged(hlo: str) -> list[str]:
+    """Fusions, sorts, gathers, scatters and custom calls outside the fused
+    computations that lie outside every ``bbc.*`` stage.  Sorts, gathers,
+    scatters, kernels and custom fusions (the emitters of gathers and
+    scatters) need a stage of their own; a loop fusion the compiler rooted
+    in an op with no source (a bitcast, say) is judged by the ops inside
+    it; only known compiler-made calls and fusions have no source op."""
+    comps = _computations(hlo)
+    fused = {c for lines in comps.values() for line in lines
+             if " fusion(" in line for c in _CALLS.findall(line)}
+    bad = []
+    for cname, lines in comps.items():
+        if cname in fused:
+            continue
+        for line in lines:
+            inst = _INST.match(line)
+            if not inst or inst.group(2) not in SEARCH_OPS:
+                continue
+            op = inst.group(2)
+            target = re.search(r'custom_call_target="([^"]*)"', line)
+            if op == "custom-call" and target and \
+                    target.group(1) in COMPILER_CALLS:
+                continue
+            loop = op == "fusion" and "kind=kLoop" in line
+            names = _source_names(line, comps) if loop else re.findall(
+                r'op_name="([^"]*)"', line)
+            if (names or not loop) and not (
+                    names and all("/bbc." in n for n in names)):
+                bad.append(inst.group(1))
+    return bad
+
+
+@pytest.mark.parametrize("method", ["ivfpq", "ivfrabitq"])
+def test_searcher_stages_on_tpu(one_chip, monkeypatch, method):
+    """The served searcher (``backend="pallas"``, ``fused=True``, d=128,
+    B=16) compiled for the chip: every fusion, sort, gather, scatter and
+    custom call that comes from the program lies in a ``bbc.*`` stage, all
+    six stages are there, and the Pallas kernels keep their wrappers'
+    names, which the benchmark's roofline readers match."""
+    import numpy as np
+
+    from repro.data import synthetic
+    from repro.index import ivf as ivf_mod
+    from repro.index import search
+    from repro.kernels import ops
+    d, b, n, c = 128, 16, 4096, 32
+    x = jnp.asarray(synthetic.clustered(np.random.default_rng(0), n, d,
+                                        n_centers=c))
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+
+    def shapes(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    qs = jax.ShapeDtypeStruct((b, d), F32, sharding=one_chip)
+    jax.clear_caches()          # no CPU trace of a kernel wrapper is reused
+    try:
+        if method == "ivfpq":
+            index = search.build_pq_index(jax.random.key(0), x, c,
+                                          n_sub=d // 4, n_bits=4, n_iter=2)
+            lay = ivf_mod.flat_layout(index.ivf)
+            lowered = search.ivf_pq_search_batch.lower(
+                shapes(index), qs, shapes(lay), k=500, n_probe=8,
+                n_cand=2000, use_bbc=True, m=M_BUCKETS, backend="pallas",
+                fused=True)
+            kernels = {"fused_scan_batch."}
+        else:
+            index = search.build_rabitq_index(jax.random.key(0), x, c,
+                                              n_iter=2)
+            lay = ivf_mod.flat_layout(index.ivf)
+            lowered = search.ivf_rabitq_search_batch.lower(
+                shapes(index), qs, shapes(lay), k=500, n_probe=8,
+                use_bbc=True, m=M_BUCKETS, backend="pallas", fused=True,
+                stream=shapes(search.rabitq_stream(index, lay)))
+            kernels = {"fused_rabitq_scan_batch.", "l2_exact_batch."}
+        hlo = lowered.compile().as_text()
+    finally:
+        jax.clear_caches()      # nor is this compile's trace reused on CPU
+    assert "tpu_custom_call" in hlo
+    assert _unstaged(hlo) == []
+    assert set(re.findall(r"bbc\.[a-z]+", hlo)) == STAGES
+    calls = [_INST.match(line).group(1) for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert {k for k in KERNELS if any(c.startswith(k) for c in calls)} \
+        == kernels
