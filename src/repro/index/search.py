@@ -640,10 +640,13 @@ def ivf_pq_search_batch(
     (default on TPU) the whole estimate+bucketize+hist+early-exact pass is
     ``ops.fused_scan_batch`` — Alg. 4's early re-ranking happens while the
     vector tile is VMEM-resident and the second gather pass covers only the
-    stragglers.  With ``fused=False`` (default on CPU, where there is no
-    fusion win to collect) exact distances are computed once for the final
-    selection; results are identical, only the ``n_second_pass`` accounting
-    differs.
+    stragglers.  When ``n_cand`` covers a quarter of the stream or more
+    (``4 * n_cand >= n_flat``), the fused pass early-exacts every lane and
+    the n_cand cut is a (B, n_flat) mask (``_dense_select``): no compaction,
+    no n_cand-wide gathers, no second pass.  With ``fused=False`` (default
+    on CPU, where there is no fusion win to collect) exact distances are
+    computed once for the final selection; results are identical, only the
+    ``n_second_pass`` accounting differs.
 
     With ``pred_state`` the blunt n_cand cut is replaced by the predictive
     early-exact pool: exact distances are spent on the ~pred_count candidates
@@ -715,13 +718,29 @@ def ivf_pq_search_batch(
                     m=m)
             )(sample_est)
 
+        # Dense regime: the selection is a large share of the stream, so
+        # the kernel exacts every lane (tau_pred = the overflow bucket,
+        # nmiss = 0) and the n_cand cut stays a full-width mask.
+        tau_pred = (jnp.full((b,), m, jnp.int32) if dense_rerank
+                    else plans.tau_pred)
         with jax.named_scope("bbc.scan"):
             stream_vecs = index.vectors[layout.order]
             est, bucket, hist, early, nmiss = ops.fused_scan_batch(
                 stream_codes, stream_vecs, lane_valid, luts, qs,
                 plans.cb.d_min, plans.cb.delta, plans.cb.ew_map, m,
-                plans.tau_pred, backend=backend)
+                tau_pred, backend=backend)
             est = jnp.where(lane_valid, est, INF)
+        if dense_rerank:
+            with jax.named_scope("bbc.collect"):
+                selected = _dense_select(est, bucket, hist, lane_valid,
+                                         n_cand)
+            with jax.named_scope("bbc.rerank"):
+                ex = jnp.where(selected, early, INF)
+            with jax.named_scope("bbc.final"):
+                neg, pos = jax.lax.top_k(-ex, k)
+                ids = jnp.where(jnp.isfinite(neg), layout.order[pos], -1)
+                n_sel = jnp.sum(selected, axis=1).astype(jnp.int32)
+                return SearchResult(-neg, ids, n_sel, jnp.zeros_like(n_sel))
         with jax.named_scope("bbc.collect"):
             positions = jnp.arange(n_flat, dtype=jnp.int32)
             _, sel_pos = col.collect_batch(est, positions, lane_valid, bucket,
@@ -1491,10 +1510,12 @@ def _sample_spec_tau(cbs, sample: jax.Array, count: int,
     return jnp.where(rank >= n_valid, m, tau)
 
 
-def _kth_value_mask(vals: jax.Array, ids: jax.Array, kth: int) -> jax.Array:
+def _kth_value_mask(vals: jax.Array, ids: jax.Array,
+                    kth: int | jax.Array) -> jax.Array:
     """Exact-width mask of the per-row ``kth`` smallest (value, global-id)
-    pairs: every lane strictly below the kth-smallest value, plus the
-    smallest-id lanes at the boundary value up to the remaining width.
+    pairs (``kth`` one width, or (rows,) widths): every lane strictly below
+    the kth-smallest value, plus the smallest-id lanes at the boundary value
+    up to the remaining width.
     Global ids are unique, so the kept SET is a deterministic function of
     the (value, id) multiset — identical for the batched stream order and
     the sharded gathered-pool order.  PQ estimates tie exactly whenever two
@@ -1531,6 +1552,32 @@ def _kth_value_mask(vals: jax.Array, ids: jax.Array, kth: int) -> jax.Array:
         thi = jnp.where(ok, mid, thi)
         tlo = jnp.where(ok, tlo, mid + 1)
     return below | (tied & (eid <= thi[:, None]))
+
+
+def _dense_select(est: jax.Array, bucket: jax.Array, hist: jax.Array,
+                  valid: jax.Array, n_cand: int) -> jax.Array:
+    """(B, n) mask of each row's ``n_cand`` smallest (estimate, stream
+    position) valid lanes, or of every valid lane when fewer exist: the set
+    ``col.collect_batch`` selects, kept at full width.  Lanes below the
+    threshold bucket are in; the cut is made only inside it, by
+    ``_kth_value_mask`` on (estimate, position), and skipped when no row's
+    buckets up to the threshold hold more than ``n_cand`` lanes.  Both
+    branches give the same mask, so batch-mates stay independent."""
+    tau, n_before = jax.vmap(rb.threshold_bucket, in_axes=(0, None))(
+        hist, n_cand)
+    below = valid & (bucket < tau[:, None])
+    at_tau = valid & (bucket == tau[:, None])
+    n_at = jnp.take_along_axis(hist, tau[:, None], axis=1)[:, 0]
+
+    def cut(_):
+        # |est| folds a -0.0 onto +0.0, whose bit pattern the bisection
+        # orders; positions break ties, as the compaction's order does
+        vals = jnp.where(at_tau, jnp.abs(est), INF)
+        pos = jnp.arange(est.shape[1], dtype=jnp.int32)
+        return at_tau & _kth_value_mask(vals, pos, n_cand - n_before)
+
+    over = jnp.any(n_before + n_at > n_cand)
+    return below | jax.lax.cond(over, cut, lambda _: at_tau, None)
 
 
 def _topk_est_id(est: jax.Array, gids: jax.Array, width: int):

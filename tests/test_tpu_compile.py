@@ -240,7 +240,12 @@ def _unstaged(hlo: str) -> list[str]:
     return bad
 
 
-@pytest.mark.parametrize("method", ["ivfpq", "ivfrabitq"])
+# IVF+PQ's n_cand: 2000 of a 4096-lane stream is its dense regime (4 *
+# n_cand >= n_flat, full-width selection), 1000 its compaction path
+PQ_N_CAND = {"ivfpq": 2000, "ivfpq_sparse": 1000}
+
+
+@pytest.mark.parametrize("method", ["ivfpq", "ivfpq_sparse", "ivfrabitq"])
 def test_searcher_stages_on_tpu(one_chip, monkeypatch, method):
     """The served searcher (``backend="pallas"``, ``fused=True``, d=128,
     B=16) compiled for the chip: every fusion, sort, gather, scatter and
@@ -265,14 +270,14 @@ def test_searcher_stages_on_tpu(one_chip, monkeypatch, method):
     qs = jax.ShapeDtypeStruct((b, d), F32, sharding=one_chip)
     jax.clear_caches()          # no CPU trace of a kernel wrapper is reused
     try:
-        if method == "ivfpq":
+        if method in PQ_N_CAND:
             index = search.build_pq_index(jax.random.key(0), x, c,
                                           n_sub=d // 4, n_bits=4, n_iter=2)
             lay = ivf_mod.flat_layout(index.ivf)
             lowered = search.ivf_pq_search_batch.lower(
                 shapes(index), qs, shapes(lay), k=500, n_probe=8,
-                n_cand=2000, use_bbc=True, m=M_BUCKETS, backend="pallas",
-                fused=True)
+                n_cand=PQ_N_CAND[method], use_bbc=True, m=M_BUCKETS,
+                backend="pallas", fused=True)
             kernels = {"fused_scan_batch."}
         else:
             index = search.build_rabitq_index(jax.random.key(0), x, c,

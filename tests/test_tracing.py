@@ -28,6 +28,7 @@ from repro.serving.state import ServingState
 
 N, D, C = 2000, 32, 16
 K, N_PROBE, N_CAND, M = 50, 4, 200, 128
+N_CAND_DENSE = 600       # 4 * N_CAND_DENSE >= n_flat: the dense regime
 ALL = {"bbc.route", "bbc.plan", "bbc.scan", "bbc.collect", "bbc.rerank",
        "bbc.final"}
 SPANS = ("serving.assemble", "serving.dispatch", "serving.wait",
@@ -46,9 +47,9 @@ def data():
                 lay_rq=ivf_mod.flat_layout(rbq.ivf))
 
 
-def _pq(d, **kw):
+def _pq(d, n_cand=N_CAND, **kw):
     return search.ivf_pq_search_batch.lower(
-        d["pq"], d["qs"], d["lay_pq"], k=K, n_probe=N_PROBE, n_cand=N_CAND,
+        d["pq"], d["qs"], d["lay_pq"], k=K, n_probe=N_PROBE, n_cand=n_cand,
         m=M, **kw)
 
 
@@ -71,6 +72,8 @@ def _pred():
 BRANCHES = {
     "pq.bbc": (lambda d: _pq(d, use_bbc=True), ALL),
     "pq.bbc_fused": (lambda d: _pq(d, use_bbc=True, fused=True), ALL),
+    "pq.bbc_fused_dense": (lambda d: _pq(d, n_cand=N_CAND_DENSE,
+                                         use_bbc=True, fused=True), ALL),
     "pq.no_bbc": (lambda d: _pq(d), ALL),
     "pq.predictive": (lambda d: _pq(d, use_bbc=True, pred_state=_pred()),
                       ALL),
