@@ -34,22 +34,43 @@ class IVFIndex(NamedTuple):
 
 def build(key: jax.Array, x: jax.Array, n_clusters: int, n_iter: int = 10,
           lane: int = 128) -> IVFIndex:
-    """k-means + padded member table.  Host-side packing (build is offline)."""
-    cent, a = km.kmeans(key, x, n_clusters, n_iter)
-    a_np = np.asarray(a)
+    """k-means + padded member table (see ``build_assigned``)."""
+    return build_assigned(key, x, n_clusters, n_iter, lane)[0]
+
+
+def build_assigned(key: jax.Array, x: jax.Array, n_clusters: int,
+                   n_iter: int = 10,
+                   lane: int = 128) -> tuple[IVFIndex, jax.Array]:
+    """k-means + padded member table, and the (n,) assignment it packed:
+    row i is a member of list ``a[i]``.  Members are packed on the host by
+    one stable argsort of the assignment, so each list holds its ids in
+    ascending order (build is offline)."""
+    n = x.shape[0]
+    chunks = km.n_chunks(n, n_clusters)
+    with jax.profiler.TraceAnnotation("index.kmeans", rows=n, chunks=chunks,
+                                      n_clusters=n_clusters):
+        cent = jax.block_until_ready(km.lloyd(key, x, n_clusters, n_iter))
+    with jax.profiler.TraceAnnotation("index.assign", rows=n, chunks=chunks,
+                                      n_clusters=n_clusters):
+        a = jax.block_until_ready(km.assign_chunked(x, cent))
+        a_np = np.asarray(a)
     sizes = np.bincount(a_np, minlength=n_clusters)
     cap = int(max(int(sizes.max()), 1))
     cap = ((cap + lane - 1) // lane) * lane
-    ids = np.full((n_clusters, cap), -1, np.int32)
-    for c in range(n_clusters):
-        mem = np.where(a_np == c)[0]
-        ids[c, : len(mem)] = mem
-    return IVFIndex(
-        centroids=cent,
-        member_ids=jnp.asarray(ids),
-        member_valid=jnp.asarray(ids >= 0),
-        cluster_sizes=jnp.asarray(sizes.astype(np.int32)),
-    )
+    with jax.profiler.TraceAnnotation("index.pack", rows=n,
+                                      n_clusters=n_clusters, cap=cap):
+        order = np.argsort(a_np, kind="stable").astype(np.int32)
+        starts = np.cumsum(sizes) - sizes
+        slot = np.arange(n) - np.repeat(starts, sizes)
+        ids = np.full((n_clusters, cap), -1, np.int32)
+        ids[np.repeat(np.arange(n_clusters), sizes), slot] = order
+        index = IVFIndex(
+            centroids=cent,
+            member_ids=jnp.asarray(ids),
+            member_valid=jnp.asarray(ids >= 0),
+            cluster_sizes=jnp.asarray(sizes.astype(np.int32)),
+        )
+    return index, a
 
 
 def route(index: IVFIndex, q: jax.Array, n_probe: int) -> jax.Array:
@@ -130,23 +151,19 @@ class FlatLayout(NamedTuple):
 
 
 def flat_layout(index: IVFIndex, lane: int = 128) -> FlatLayout:
-    """Host-side packing of the member table into a FlatLayout (offline)."""
+    """Host-side packing of the member table into a FlatLayout (offline):
+    the valid member ids row by row, which is cluster-major."""
     ids = np.asarray(index.member_ids)
     sizes = np.asarray(index.cluster_sizes).astype(np.int64)
     n_clusters = ids.shape[0]
     n = int(sizes.sum())
     n_flat = ((n + lane - 1) // lane) * lane
     order = np.zeros(n_flat, np.int32)
+    order[:n] = ids[ids >= 0]
     cluster_of = np.full(n_flat, n_clusters, np.int32)
+    cluster_of[:n] = np.repeat(np.arange(n_clusters, dtype=np.int32), sizes)
     offsets = np.zeros(n_clusters + 1, np.int32)
-    pos = 0
-    for c in range(n_clusters):
-        sz = int(sizes[c])
-        offsets[c] = pos
-        order[pos:pos + sz] = ids[c, :sz]
-        cluster_of[pos:pos + sz] = c
-        pos += sz
-    offsets[n_clusters] = pos
+    offsets[1:] = np.cumsum(sizes)
     valid = np.arange(n_flat) < n
     return FlatLayout(
         order=jnp.asarray(order),

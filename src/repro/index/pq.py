@@ -8,6 +8,7 @@ provides training/encoding and the jnp reference estimator.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -38,18 +39,32 @@ def train(key: jax.Array, x: jax.Array, n_sub: int, n_bits: int = 4,
     n, d = x.shape
     assert d % n_sub == 0, (d, n_sub)
     dsub = d // n_sub
-    xs = x.reshape(n, n_sub, dsub)
     keys = jax.random.split(key, n_sub)
-    cents = []
-    for m in range(n_sub):  # offline; loop fine
-        c, _ = km.kmeans(keys[m], xs[:, m, :], 2 ** n_bits, n_iter)
-        cents.append(c)
-    return PQCodebook(centroids=jnp.stack(cents))
+    with jax.profiler.TraceAnnotation(
+            "pq.train", rows=n, chunks=km.n_chunks(n, 2 ** n_bits),
+            n_clusters=2 ** n_bits):
+        cents = [km.lloyd(keys[m], x[:, m * dsub:(m + 1) * dsub],
+                          2 ** n_bits, n_iter)
+                 for m in range(n_sub)]   # offline; loop fine
+        return jax.block_until_ready(PQCodebook(centroids=jnp.stack(cents)))
 
 
-@jax.jit
 def encode(cb: PQCodebook, x: jax.Array) -> jax.Array:
-    """(n, M) uint8 codes."""
+    """(n, M) uint8 codes, encoded in row chunks so that no (rows, M, 2^B)
+    distance array exceeds ``kmeans.CHUNK_BYTES``."""
+    n, width = x.shape[0], cb.n_sub * cb.n_codes
+    with jax.profiler.TraceAnnotation("pq.encode", rows=n,
+                                      chunks=km.n_chunks(n, width)):
+        return jax.block_until_ready(_encode(cb, x, km.chunk_rows(width)))
+
+
+@functools.partial(jax.jit, static_argnames=("rows",))
+def _encode(cb: PQCodebook, x: jax.Array, rows: int) -> jax.Array:
+    return km.map_chunks(x, rows, lambda xc: _encode_rows(cb, xc),
+                         jnp.zeros((x.shape[0], cb.n_sub), jnp.uint8))
+
+
+def _encode_rows(cb: PQCodebook, x: jax.Array) -> jax.Array:
     n, d = x.shape
     xs = x.reshape(n, cb.n_sub, cb.dsub)
 

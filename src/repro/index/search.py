@@ -49,6 +49,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import PartitionSpec as P
 
 from repro.core import buffer as rb
@@ -122,22 +123,23 @@ def build_pq_index(key, x, n_clusters: int, n_sub: int | None = None,
     d = x.shape[1]
     n_sub = n_sub or d // 4          # paper: M = d/4, B = 4
     k1, k2 = jax.random.split(key)
-    index = ivf_mod.build(k1, x, n_clusters, n_iter)
-    cb = pq_mod.train(k2, x, n_sub, n_bits, n_iter)
-    codes = pq_mod.encode(cb, x)
+    with jax.profiler.TraceAnnotation("index.build", rows=x.shape[0],
+                                      n_clusters=n_clusters):
+        index = ivf_mod.build(k1, x, n_clusters, n_iter)
+        cb = pq_mod.train(k2, x, n_sub, n_bits, n_iter)
+        codes = pq_mod.encode(cb, x)
     return PQIndex(ivf=index, pq=cb, codes=codes, vectors=x)
 
 
 def build_rabitq_index(key, x, n_clusters: int, n_iter: int = 10) -> RabitqIndex:
+    """Each row is encoded against the centroid of the list ``ivf.build``
+    packed it into, the one the searchers read it with."""
     k1, k2 = jax.random.split(key)
-    index = ivf_mod.build(k1, x, n_clusters, n_iter)
-    assignment = jnp.argmin(
-        jnp.sum(x * x, 1, keepdims=True)
-        - 2 * jnp.matmul(x, index.centroids.T, precision="highest")
-        + jnp.sum(index.centroids ** 2, 1),
-        axis=1,
-    )
-    rq = rq_mod.encode(k2, x, index.centroids, assignment)
+    with jax.profiler.TraceAnnotation("index.build", rows=x.shape[0],
+                                      n_clusters=n_clusters):
+        index, assignment = ivf_mod.build_assigned(k1, x, n_clusters, n_iter)
+        rq = jax.block_until_ready(
+            rq_mod.encode(k2, x, index.centroids, assignment))
     return RabitqIndex(ivf=index, rq=rq, vectors=x)
 
 
@@ -235,6 +237,65 @@ def _pq_sample_est(layout: ivf_mod.FlatLayout, probed: jax.Array,
         return jnp.where(ok, jnp.sqrt(jnp.maximum(e, 0.0)), INF)
 
     return jax.lax.map(one, (spos, sok, luts))
+
+
+# Lanes per fused-scan call.  The kernel's per-lane outputs are (lanes, B)
+# arrays, which the chip lays out with B padded to 128 lanes (8x at
+# B=16): one call over a 10M-lane stream would need 14 GiB for them alone.
+# A stream of at most SCAN_CHUNK lanes is scanned in one call.
+SCAN_CHUNK = 1 << 20
+
+
+def _fused_scan_stream(index: "PQIndex", stream_codes: jax.Array,
+                       layout: ivf_mod.FlatLayout, lane_valid: jax.Array,
+                       luts: jax.Array, qs: jax.Array, d_min: jax.Array,
+                       delta: jax.Array, ew_map: jax.Array, m: int,
+                       tau_pred: jax.Array, backend: str | None):
+    """``ops.fused_scan_batch`` over the layout-ordered stream, with the
+    vectors gathered into stream order, ``SCAN_CHUNK`` lanes per call.
+    Every lane's outputs are the one-call outputs, and the histograms and
+    miss counts are integer sums over the chunks."""
+    def scan(codes, order, valid):
+        return ops.fused_scan_batch(codes, index.vectors[order], valid,
+                                    luts, qs, d_min, delta, ew_map, m,
+                                    tau_pred, backend=backend)
+    n_flat = layout.n_flat
+    if n_flat <= SCAN_CHUNK:
+        return scan(stream_codes, layout.order, lane_valid)
+    b = qs.shape[0]
+
+    def add(acc, out, start):
+        est, bucket, early = (jax.lax.dynamic_update_slice_in_dim(
+            a, _lanes_minor(o), start, 1)
+            for a, o in zip(acc[:3], (out[0], out[1], out[3])))
+        return est, bucket, early, acc[3] + out[2], acc[4] + out[4]
+
+    def chunk(i, acc):
+        start = i * SCAN_CHUNK
+        return add(acc, scan(
+            jax.lax.dynamic_slice_in_dim(stream_codes, start, SCAN_CHUNK),
+            jax.lax.dynamic_slice_in_dim(layout.order, start, SCAN_CHUNK),
+            jax.lax.dynamic_slice_in_dim(lane_valid, start, SCAN_CHUNK, 1)),
+            start)
+    full = n_flat // SCAN_CHUNK
+    acc = (jnp.zeros((b, n_flat), jnp.float32),
+           jnp.zeros((b, n_flat), jnp.int32),
+           jnp.zeros((b, n_flat), jnp.float32),
+           jnp.zeros((b, m + 1), jnp.int32), jnp.zeros((b,), jnp.int32))
+    acc = jax.lax.fori_loop(0, full, chunk, acc)
+    if n_flat % SCAN_CHUNK:
+        start = full * SCAN_CHUNK
+        acc = add(acc, scan(stream_codes[start:], layout.order[start:],
+                            lane_valid[:, start:]), start)
+    est, bucket, early, hist, nmiss = acc
+    return (_lanes_minor(est), _lanes_minor(bucket), hist,
+            _lanes_minor(early), nmiss)
+
+
+def _lanes_minor(a: jax.Array) -> jax.Array:
+    """``a`` (B, lanes) laid out with the lanes minor, so that the compiler
+    does not keep it in the kernel's lane-padded (lanes, B) order."""
+    return with_layout_constraint(a, Layout(major_to_minor=(0, 1)))
 
 
 def _predictive_select(est: jax.Array, bucket: jax.Array, hist: jax.Array,
@@ -724,11 +785,10 @@ def ivf_pq_search_batch(
         tau_pred = (jnp.full((b,), m, jnp.int32) if dense_rerank
                     else plans.tau_pred)
         with jax.named_scope("bbc.scan"):
-            stream_vecs = index.vectors[layout.order]
-            est, bucket, hist, early, nmiss = ops.fused_scan_batch(
-                stream_codes, stream_vecs, lane_valid, luts, qs,
+            est, bucket, hist, early, nmiss = _fused_scan_stream(
+                index, stream_codes, layout, lane_valid, luts, qs,
                 plans.cb.d_min, plans.cb.delta, plans.cb.ew_map, m,
-                tau_pred, backend=backend)
+                tau_pred, backend)
             est = jnp.where(lane_valid, est, INF)
         if dense_rerank:
             with jax.named_scope("bbc.collect"):
@@ -821,11 +881,10 @@ def _ivf_pq_predictive_batch(index, qs, layout, probed, lane_valid,
 
     with jax.named_scope("bbc.scan"):
         if fused:
-            stream_vecs = index.vectors[layout.order]
-            est, bucket, hist, early, nmiss = ops.fused_scan_batch(
-                stream_codes, stream_vecs, lane_valid, luts, qs,
+            est, bucket, hist, early, nmiss = _fused_scan_stream(
+                index, stream_codes, layout, lane_valid, luts, qs,
                 cbs.d_min, cbs.delta, cbs.ew_map, m,
-                jnp.full((b,), tau_pred, jnp.int32), backend=backend)
+                jnp.full((b,), tau_pred, jnp.int32), backend)
             est = jnp.where(lane_valid, est, INF)
             n_early = (jnp.sum(lane_valid, axis=1) - nmiss).astype(jnp.int32)
         else:

@@ -5,9 +5,9 @@ for a topology that is described, not attached).
 Interpret mode cannot show what Mosaic refuses — unaligned blocks, slices
 it cannot lower, scoped-VMEM overflow — so every kernel the served search
 path runs is compiled here with ``interpret=False`` at real widths (SIFT's
-d=128 and GIST's d=960), the serving batch (B=32) and a singleton batch,
-TILE=256 (512 for the bucket histogram, its own tile) and the engine's
-bucket count.  Shapes are padded exactly as the
+d=128, DEEP's d=96 and GIST's d=960), the serving batch (B=32) and a
+singleton batch, TILE=256 (512 for the bucket histogram, its own tile) and
+the engine's bucket count.  Shapes are padded exactly as the
 ``kernels.ops`` wrappers pad them before calling the kernels.
 
 The topology is described inside a fixture (never at import): only one
@@ -79,7 +79,7 @@ def _compile(one_chip, fn, *shapes):
     return compiled
 
 
-WIDTHS = [128, 960]
+WIDTHS = [96, 128, 960]
 BATCHES = [1, 32]
 
 

@@ -190,3 +190,55 @@ def test_outcomes_identical_with_the_profiler_on(served):
                                    b.t_done, b.k_effective)
         np.testing.assert_array_equal(a.ids, b.ids)
         np.testing.assert_array_equal(a.dists, b.dists)
+
+
+# --------------------------------------------------------------------------
+# build spans
+# --------------------------------------------------------------------------
+
+BUILD_SPANS = {"index.kmeans": {"rows", "chunks", "n_clusters"},
+               "index.assign": {"rows", "chunks", "n_clusters"},
+               "index.pack": {"rows", "n_clusters", "cap"},
+               "pq.train": {"rows", "chunks", "n_clusters"},
+               "pq.encode": {"rows", "chunks"}}
+
+
+def _read_build_spans(log_dir: str) -> list[tuple]:
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    return [(e.name, int(e.start_ns), int(e.start_ns + e.duration_ns),
+             dict(e.stats))
+            for plane in ProfileData.from_file(path).planes
+            for line in plane.lines for e in line.events
+            if e.name.split(".")[0] in ("index", "pq")]
+
+
+@pytest.mark.parametrize("method", ["ivfpq", "ivfrabitq"])
+def test_build_phases_carry_spans_under_index_build(data, tmp_path, method):
+    """A profiled build marks each phase with a host span nested under
+    ``index.build``, carrying its counts."""
+    x = data["x"]
+    with jax.profiler.trace(str(tmp_path)):
+        if method == "ivfpq":
+            index = search.build_pq_index(jax.random.key(3), x, C, n_iter=2)
+        else:
+            index = search.build_rabitq_index(jax.random.key(3), x, C,
+                                              n_iter=2)
+    spans = _read_build_spans(str(tmp_path))
+    (_, a, b, top), = [s for s in spans if s[0] == "index.build"]
+    assert top["rows"] == N and top["n_clusters"] == C
+    want = (BUILD_SPANS if method == "ivfpq" else
+            {n: v for n, v in BUILD_SPANS.items() if n.startswith("index")})
+    inner = {name: stats for name, *_, stats in spans if name in want}
+    assert set(inner) == set(want)
+    for name, sa, sb, stats in spans:
+        if name in want:
+            assert a <= sa <= sb <= b, name
+            assert want[name] <= set(stats), (name, stats)
+            assert stats["rows"] == N
+    assert inner["index.pack"]["cap"] == index.ivf.cap
+    assert inner["index.kmeans"]["n_clusters"] == C
+    assert inner["index.kmeans"]["chunks"] == 1
+    if method == "ivfpq":
+        assert inner["pq.train"]["n_clusters"] == 16
