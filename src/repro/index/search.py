@@ -701,13 +701,18 @@ def ivf_pq_search_batch(
     (default on TPU) the whole estimate+bucketize+hist+early-exact pass is
     ``ops.fused_scan_batch`` — Alg. 4's early re-ranking happens while the
     vector tile is VMEM-resident and the second gather pass covers only the
-    stragglers.  When ``n_cand`` covers a quarter of the stream or more
-    (``4 * n_cand >= n_flat``), the fused pass early-exacts every lane and
-    the n_cand cut is a (B, n_flat) mask (``_dense_select``): no compaction,
-    no n_cand-wide gathers, no second pass.  With ``fused=False`` (default
-    on CPU, where there is no fusion win to collect) exact distances are
-    computed once for the final selection; results are identical, only the
-    ``n_second_pass`` accounting differs.
+    stragglers.  In the dense regime (``_pq_dense_regime``: ``n_cand``
+    covers a quarter of the stream, or a query's expected probed lanes),
+    the fused pass early-exacts every lane and the n_cand cut is a
+    (B, n_flat) mask (``_dense_select``): no compaction, no n_cand-wide
+    gathers, no second pass.  Its final top-k runs over the probed tiles
+    when they are at most a quarter of the stream (``_pq_probed_final``),
+    else over the stream.  So DEEP-10M's shard (n_cand 400,000 above every
+    query's ~300k probed lanes of 10M) takes this path, not the
+    compaction's overflow fallback over the stream.  With ``fused=False``
+    (default on CPU, where there is no fusion win to collect) exact
+    distances are computed once for the final selection; results are
+    identical, only the ``n_second_pass`` accounting differs.
 
     With ``pred_state`` the blunt n_cand cut is replaced by the predictive
     early-exact pool: exact distances are spent on the ~pred_count candidates
@@ -737,6 +742,8 @@ def ivf_pq_search_batch(
             index, qs, layout, probed, lane_valid, stream_codes, luts, k,
             n_probe, n_cand, m, backend, fused, pred_state, pred_count)
 
+    # the unfused paths' re-rank: one stream-wide matmul beats n_cand
+    # per-row gathers once the selection is a quarter of the stream
     dense_rerank = 4 * n_cand >= layout.n_flat
 
     if not use_bbc:
@@ -779,10 +786,12 @@ def ivf_pq_search_batch(
                     m=m)
             )(sample_est)
 
-        # Dense regime: the selection is a large share of the stream, so
-        # the kernel exacts every lane (tau_pred = the overflow bucket,
-        # nmiss = 0) and the n_cand cut stays a full-width mask.
-        tau_pred = (jnp.full((b,), m, jnp.int32) if dense_rerank
+        # Dense regime: the selection is a large share of the stream or of
+        # the probed lanes, so the kernel exacts every lane (tau_pred = the
+        # overflow bucket, nmiss = 0) and the n_cand cut stays a
+        # full-width mask.
+        dense = _pq_dense_regime(n_cand, n_probe, n_flat, ivf.n_clusters)
+        tau_pred = (jnp.full((b,), m, jnp.int32) if dense
                     else plans.tau_pred)
         with jax.named_scope("bbc.scan"):
             est, bucket, hist, early, nmiss = _fused_scan_stream(
@@ -790,14 +799,26 @@ def ivf_pq_search_batch(
                 plans.cb.d_min, plans.cb.delta, plans.cb.ew_map, m,
                 tau_pred, backend)
             est = jnp.where(lane_valid, est, INF)
-        if dense_rerank:
+        if dense:
             with jax.named_scope("bbc.collect"):
                 selected = _dense_select(est, bucket, hist, lane_valid,
                                          n_cand)
             with jax.named_scope("bbc.rerank"):
                 ex = jnp.where(selected, early, INF)
             with jax.named_scope("bbc.final"):
-                neg, pos = jax.lax.top_k(-ex, k)
+                if _pq_probed_final(k, n_probe, ivf.cap, n_flat):
+                    # Every selected lane lies in a probed tile.  Ascending
+                    # cluster ids give ascending offsets, so the tiles keep
+                    # stream order over live lanes, and top_k's ties (lower
+                    # index first) fall as at full width.
+                    tpos, tok = ivf_mod.tile_positions(
+                        layout, jnp.sort(probed, axis=1), ivf.cap)
+                    ex_t = jnp.where(
+                        tok, jnp.take_along_axis(ex, tpos, axis=1), INF)
+                    neg, at = jax.lax.top_k(-ex_t, k)
+                    pos = jnp.take_along_axis(tpos, at, axis=1)
+                else:
+                    neg, pos = jax.lax.top_k(-ex, k)
                 ids = jnp.where(jnp.isfinite(neg), layout.order[pos], -1)
                 n_sel = jnp.sum(selected, axis=1).astype(jnp.int32)
                 return SearchResult(-neg, ids, n_sel, jnp.zeros_like(n_sel))
@@ -1611,6 +1632,25 @@ def _kth_value_mask(vals: jax.Array, ids: jax.Array,
         thi = jnp.where(ok, mid, thi)
         tlo = jnp.where(ok, tlo, mid + 1)
     return below | (tied & (eid <= thi[:, None]))
+
+
+def _pq_dense_regime(n_cand: int, n_probe: int, n_flat: int,
+                     n_clusters: int) -> bool:
+    """Whether the fused PQ searcher selects with ``_dense_select``: when
+    ``n_cand`` covers a quarter of the stream, or a query's expected probed
+    lanes (``n_probe / n_clusters`` of the stream).  There the compaction
+    would keep most probed lanes, or overflow and fall back to a
+    stream-wide ``top_k``.  Static shapes only, so one trace per shape and
+    a batch's rows equal their B=1 calls."""
+    return 4 * n_cand >= n_flat or n_cand * n_clusters >= n_probe * n_flat
+
+
+def _pq_probed_final(k: int, n_probe: int, cap: int, n_flat: int) -> bool:
+    """Whether the dense regime's final ``top_k`` runs over the probed
+    tiles (``n_probe * cap`` lanes) instead of the stream: when they hold
+    ``k`` lanes and are at most a quarter of the stream, so that the
+    tile gather buys a sort at least 4x narrower."""
+    return k <= n_probe * cap <= n_flat // 4
 
 
 def _dense_select(est: jax.Array, bucket: jax.Array, hist: jax.Array,

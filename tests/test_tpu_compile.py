@@ -240,12 +240,17 @@ def _unstaged(hlo: str) -> list[str]:
     return bad
 
 
-# IVF+PQ's n_cand: 2000 of a 4096-lane stream is its dense regime (4 *
-# n_cand >= n_flat, full-width selection), 1000 its compaction path
-PQ_N_CAND = {"ivfpq": 2000, "ivfpq_sparse": 1000}
+# IVF+PQ's (lists, n_probe, n_cand) over a 4096-lane stream: 2000 is its
+# dense regime (4 * n_cand >= n_flat, full-width selection), 1000 its
+# compaction path (1000 * 32 < 8 * 4096); over 128 lists n_cand 512 covers
+# the ~256 probed lanes, so it is dense, and the final top-k runs over the
+# 8 probed tiles of 128 lanes (a quarter of the stream)
+PQ_CASES = {"ivfpq": (32, 8, 2000), "ivfpq_sparse": (32, 8, 1000),
+            "ivfpq_probed": (128, 8, 512)}
 
 
-@pytest.mark.parametrize("method", ["ivfpq", "ivfpq_sparse", "ivfrabitq"])
+@pytest.mark.parametrize("method", ["ivfpq", "ivfpq_sparse", "ivfrabitq",
+                                    "ivfpq_probed"])
 def test_searcher_stages_on_tpu(one_chip, monkeypatch, method):
     """The served searcher (``backend="pallas"``, ``fused=True``, d=128,
     B=16) compiled for the chip: every fusion, sort, gather, scatter and
@@ -258,7 +263,8 @@ def test_searcher_stages_on_tpu(one_chip, monkeypatch, method):
     from repro.index import ivf as ivf_mod
     from repro.index import search
     from repro.kernels import ops
-    d, b, n, c = 128, 16, 4096, 32
+    d, b, n, k = 128, 16, 4096, 500
+    c, n_probe, n_cand = PQ_CASES.get(method, (32, 8, None))
     x = jnp.asarray(synthetic.clustered(np.random.default_rng(0), n, d,
                                         n_centers=c))
     monkeypatch.setattr(ops, "_interpret", lambda: False)
@@ -270,13 +276,18 @@ def test_searcher_stages_on_tpu(one_chip, monkeypatch, method):
     qs = jax.ShapeDtypeStruct((b, d), F32, sharding=one_chip)
     jax.clear_caches()          # no CPU trace of a kernel wrapper is reused
     try:
-        if method in PQ_N_CAND:
+        if method in PQ_CASES:
             index = search.build_pq_index(jax.random.key(0), x, c,
                                           n_sub=d // 4, n_bits=4, n_iter=2)
             lay = ivf_mod.flat_layout(index.ivf)
+            assert search._pq_dense_regime(n_cand, n_probe, lay.n_flat, c) \
+                == (method != "ivfpq_sparse")
+            assert search._pq_probed_final(k, n_probe, index.ivf.cap,
+                                           lay.n_flat) \
+                == (method == "ivfpq_probed")
             lowered = search.ivf_pq_search_batch.lower(
-                shapes(index), qs, shapes(lay), k=500, n_probe=8,
-                n_cand=PQ_N_CAND[method], use_bbc=True, m=M_BUCKETS,
+                shapes(index), qs, shapes(lay), k=k, n_probe=n_probe,
+                n_cand=n_cand, use_bbc=True, m=M_BUCKETS,
                 backend="pallas", fused=True)
             kernels = {"fused_scan_batch."}
         else:
@@ -284,7 +295,7 @@ def test_searcher_stages_on_tpu(one_chip, monkeypatch, method):
                                               n_iter=2)
             lay = ivf_mod.flat_layout(index.ivf)
             lowered = search.ivf_rabitq_search_batch.lower(
-                shapes(index), qs, shapes(lay), k=500, n_probe=8,
+                shapes(index), qs, shapes(lay), k=k, n_probe=n_probe,
                 use_bbc=True, m=M_BUCKETS, backend="pallas", fused=True,
                 stream=shapes(search.rabitq_stream(index, lay)))
             kernels = {"fused_rabitq_scan_batch.", "l2_exact_batch."}
